@@ -14,8 +14,7 @@ constrained plane), and reports
   * C×S batched-replay speedup over the scalar loop for both planes,
     with every cell verified bit-identical,
   * an informational ``jax_scan_fleet`` row timing the jitted
-    ``lax.scan`` sweep against the numpy sweep (skipped when jax is
-    not installed).
+    ``lax.scan`` sweep against the numpy sweep.
 
 Emits ``BENCH_fleet.json`` under artifacts/bench/ so regressions in
 the engine hot path surface in CI diffs. ``--smoke`` gates the
@@ -186,13 +185,7 @@ def _run_jax_scan_case(n_candidates: int = REPLAY_C,
                        n_instances: int = REPLAY_N):
     """Informational row: the fast plane's longest-path sweep as a
     jitted ``lax.scan`` (``FleetEngine(plane_backend="jax")``) vs the
-    numpy sweep, bit-identity included. Skips gracefully when jax is
-    not installed (the smoke lane runs numpy-only)."""
-    try:
-        import jax  # noqa: F401
-    except Exception as exc:                       # pragma: no cover
-        return {"case": "jax_scan_fleet", "skipped": True,
-                "reason": f"jax unavailable: {type(exc).__name__}"}
+    numpy sweep, bit-identity included."""
     template, candidates, seeds = _replay_grid(n_candidates, n_seeds,
                                                n_instances)
 
@@ -214,7 +207,6 @@ def _run_jax_scan_case(n_candidates: int = REPLAY_C,
                     for a, b in zip(jax_reports, numpy_reports))
     return {
         "case": "jax_scan_fleet",
-        "skipped": False,
         "n_candidates": n_candidates,
         "n_seeds": n_seeds,
         "n_instances": n_instances,

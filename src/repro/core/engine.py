@@ -992,7 +992,7 @@ class _PlannedBackend(BaseBackend):
         return runtimes, failed
 
 
-#: lazily-built (enable_x64, jitted sweep) pair — see _jax_sweep_fn
+#: lazily-built jitted sweep — see _jax_sweep_fn
 _JAX_SWEEP = None
 
 
@@ -1007,7 +1007,6 @@ def _jax_sweep_fn():
         import jax
         import jax.numpy as jnp
         from jax import lax
-        from jax.experimental import enable_x64
 
         @jax.jit
         def sweep(t_all, rt, order_idx, pred_idx, pred_mask):
@@ -1032,7 +1031,7 @@ def _jax_sweep_fn():
                                rt[:, order_idx].T))
             return fin.max(axis=2)
 
-        _JAX_SWEEP = (enable_x64, sweep)
+        _JAX_SWEEP = sweep
     return _JAX_SWEEP
 
 
@@ -1081,6 +1080,10 @@ class FleetEngine:
         #: ``lax.scan`` over topological ranks (x64) instead of the
         #: numpy loop — same recurrence, device-compiled
         self.plane_backend = plane_backend
+        #: the device the last jitted sweep's output lives on (``None``
+        #: until one ran) — observed from the result, so a caller can
+        #: check where the jax plane actually executed
+        self.sweep_device = None
         #: optional per-invocation runtime multipliers keyed by
         #: ``(tenant identity, function name)`` — the placement layer's
         #: co-location/noisy-neighbour model (see
@@ -1442,6 +1445,10 @@ class FleetEngine:
             constrained.append("collect_carry requested")
         if constrained:
             return {"plane": "constrained", "reasons": constrained}
+        if self.plane_backend == "jax" and not deterministic:
+            return {"plane": "fast", "reasons": [
+                "replay noise present: the longest-path sweep runs in "
+                "numpy, not as the jitted jax sweep"]}
         return {"plane": "fast", "reasons": []}
 
     def batch_eligibility(self, template: Workflow,
@@ -2084,9 +2091,13 @@ class FleetEngine:
         """The fast plane's longest-path sweep as a jitted ``lax.scan``
         over topological ranks (x64): all C×N×V finish times advance as
         one device program — the fleet-step end state this repo aims
-        at. Same recurrence, same IEEE add/max per element as the numpy
-        sweep (validated by tests). Requires jax."""
-        enable_x64, sweep = _jax_sweep_fn()
+        at. Same recurrence as the numpy sweep, bit-identical where
+        float64 is native (the CPU; validated by tests). A TPU emulates
+        float64: on a TPU v5e the results differ from numpy by up to
+        6.4e-14 relative (``chip_smoke.py`` holds them to 1e-12).
+        Requires jax."""
+        import jax
+        sweep = _jax_sweep_fn()
         order_idx = np.array([col[name] for name in order], dtype=np.int32)
         max_p = max((len(template.predecessors(n)) for n in order),
                     default=1)
@@ -2097,9 +2108,10 @@ class FleetEngine:
             for j, p in enumerate(template.predecessors(name)):
                 pred_idx[k, j] = col[p]
                 pred_mask[k, j] = True
-        with enable_x64():
-            return np.asarray(sweep(t_all, rt, order_idx, pred_idx,
-                                    pred_mask))
+        with jax.enable_x64(True):
+            fin = sweep(t_all, rt, order_idx, pred_idx, pred_mask)
+            self.sweep_device = fin.device
+            return np.asarray(fin)
 
     # -- internals -----------------------------------------------------
     def _run_degenerate(self, wf: Workflow, arrival: float) -> FleetReport:

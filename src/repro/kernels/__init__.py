@@ -13,13 +13,3 @@ Each package: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 public wrapper), ref.py (pure-jnp oracle). Kernels target TPU; CPU CI
 validates them in ``interpret=True`` mode against the oracle.
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-#: jax renamed TPUCompilerParams -> CompilerParams; support both so the
-#: kernels build against the container's pinned jax and newer releases.
-CompilerParams = getattr(_pltpu, "CompilerParams",
-                         getattr(_pltpu, "TPUCompilerParams", None))
-if CompilerParams is None:  # pragma: no cover - unsupported jax
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; this jax version is unsupported")

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 
 def _rmsnorm_kernel(x_ref, res_ref, w_ref, y_ref, new_res_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -45,7 +43,7 @@ def fused_rmsnorm_2d(x: jnp.ndarray, residual: jnp.ndarray, w: jnp.ndarray,
         out_specs=[row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((rows, d), x.dtype),
                    jax.ShapeDtypeStruct((rows, d), x.dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, residual, w)

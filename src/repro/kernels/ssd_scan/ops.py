@@ -27,15 +27,18 @@ def ssd_scan(xh: jnp.ndarray, b_mat: jnp.ndarray, c_mat: jnp.ndarray,
     assert s % q == 0, f"seq {s} not divisible by chunk {q}"
     c = s // q
 
-    xc = xh.reshape(bsz, c, q, h, p)
-    bc = b_mat.reshape(bsz, c, q, n)
+    # head-major layouts for the kernels (see kernel.py)
+    xc = xh.reshape(bsz, c, q, h, p).transpose(0, 1, 3, 2, 4)
+    bmt = b_mat.reshape(bsz, c, q, n).transpose(0, 1, 3, 2)
     cc = c_mat.reshape(bsz, c, q, n)
     la = log_a.reshape(bsz, c, q, h).astype(jnp.float32)
     dc = dt.reshape(bsz, c, q, h).astype(jnp.float32)
-    cum = jnp.cumsum(la, axis=2)                                # (b,c,q,h)
+    cum = jnp.cumsum(la, axis=2).transpose(0, 1, 3, 2)          # (b,c,h,q)
 
-    y_intra, s_chunk, chunk_decay = ssd_intra(xc, bc, cc, cum, dc,
-                                              interpret=interpret)
+    y_intra, s_chunk = ssd_intra(xc, bmt, cc, cum,
+                                 dc.transpose(0, 1, 3, 2),
+                                 interpret=interpret)
+    chunk_decay = jnp.exp(cum[..., -1])                         # (b,c,h)
 
     if h0 is None:
         h0 = jnp.zeros((bsz, h, n, p), jnp.float32)
@@ -51,4 +54,4 @@ def ssd_scan(xh: jnp.ndarray, b_mat: jnp.ndarray, c_mat: jnp.ndarray,
     h_prevs = h_prevs.transpose(1, 0, 2, 3, 4)                  # (b,c,h,n,p)
 
     y = ssd_inter(cc, cum, h_prevs, y_intra, xh.dtype, interpret=interpret)
-    return y.reshape(bsz, s, h, p), h_last
+    return y.transpose(0, 1, 3, 2, 4).reshape(bsz, s, h, p), h_last

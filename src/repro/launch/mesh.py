@@ -15,15 +15,23 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    # GSPMD sharding hints (``with_sharding_constraint`` in
+    # ``distributed/sharding.py``) may only name Auto axes, and
+    # ``jax.make_mesh`` makes Explicit ones by default
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU tests (requires >=4 forced host devices)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def describe(mesh) -> str:

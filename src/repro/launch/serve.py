@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS
 from repro.configs.registry import reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.serving import RequestQueue, ServeEngine
 
@@ -26,6 +27,7 @@ def main(argv=None):
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = reduced_config(args.arch)
     model = Model(cfg)

@@ -20,6 +20,7 @@ import jax
 from repro.configs import ARCH_IDS, SHAPES
 from repro.configs.registry import get_config, reduced_config
 from repro.distributed.fault_tolerance import ResilientLoop, StepWatchdog
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.training.data import SyntheticDataset
 from repro.training.optimizer import AdamWConfig, adamw_init
@@ -44,6 +45,7 @@ def main(argv=None):
                          "remat level before training")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = (reduced_config if args.reduced else get_config)(args.arch)
     if args.remat:

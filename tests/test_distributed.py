@@ -86,7 +86,8 @@ def test_sharded_train_step_runs_and_matches_single_device():
 
         # sharded execution on a (data=2, model=4) mesh
         from repro.configs.shapes import Shape
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh((2, 4))
         shape = Shape("t", 16, 8, "train")
         bundle = build_train_step(cfg, shape, mesh, donate=False)
         compiled = bundle.lowered.compile()

@@ -650,10 +650,29 @@ def test_jax_plane_backend_matches_numpy_bitwise():
     carry = FleetCarry(clock=0.0, warm={}, busy=[(700.0, 2.0, 512.0)])
     numpy_reports = make_engine().run_many(template, cands, seeds,
                                            carry=carry)
-    jax_reports = make_engine(plane_backend="jax").run_many(
-        template, cands, seeds, carry=carry)
+    jax_engine = make_engine(plane_backend="jax")
+    jax_reports = jax_engine.run_many(template, cands, seeds, carry=carry)
     for got, want in zip(jax_reports, numpy_reports):
         assert_reports_identical(got, want)
+    # the sweep really ran as a jax program, on JAX's default device
+    import jax
+    assert jax_engine.sweep_device.platform == jax.default_backend()
+
+
+def test_jax_plane_names_numpy_sweep_under_replay_noise():
+    """Replay noise keeps the fast plane's sweep in numpy even where the
+    jax sweep was asked for; the diagnostic names it, and the plane
+    records no device sweep."""
+    template = TOPOLOGIES["chain"]()
+    engine = _stochastic_engine(0, plane_backend="jax")
+    elig = engine.batch_eligibility(template, [])
+    assert elig["plane"] == "fast" and elig["vectorized"]
+    assert any("numpy" in r for r in elig["reasons"])
+    engine.run_many(template, candidate_sets(template, 2, seed=17),
+                    arrival_sets(2))
+    assert engine.sweep_device is None
+    assert _stochastic_engine(0).batch_eligibility(template, [])[
+        "reasons"] == []
 
 
 def test_unknown_plane_backend_rejected():
