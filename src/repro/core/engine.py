@@ -64,6 +64,7 @@ from typing import (Callable, Dict, List, Mapping, Optional, Sequence,
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.backend import BaseBackend, RuntimeBackend, as_backend
 from repro.core.cost import DEFAULT_PRICING, PricingModel
 from repro.core.dag import Workflow
@@ -994,6 +995,11 @@ class _PlannedBackend(BaseBackend):
 
 #: lazily-built jitted sweep — see _jax_sweep_fn
 _JAX_SWEEP = None
+#: (C', N, V, max_preds) signatures the jitted sweep has been given: a
+#: new one is a trace plus a compile or a persistent-cache load
+_SWEEP_SHAPES: set = set()
+#: sequence numbers of ``run_many`` calls, the ``call`` stat of their spans
+_CALLS = itertools.count(1)
 
 
 def _jax_sweep_fn():
@@ -1033,6 +1039,13 @@ def _jax_sweep_fn():
 
         _JAX_SWEEP = sweep
     return _JAX_SWEEP
+
+
+def _cell_span(call: int, plane: str):
+    """The span of one cell replayed on its own, not in a sweep;
+    counted as such."""
+    telemetry.count("fleet.cells.per_cell")
+    return telemetry.span("fleet.cell", call=call, plane=plane)
 
 
 class FleetEngine:
@@ -1340,6 +1353,10 @@ class FleetEngine:
         onto any workflow (there are no per-instance copies to write
         to); callers that need mutated workflows should use ``run``
         directly.
+
+        While a JAX profiler trace runs, the call and its phases are
+        recorded as ``fleet.*`` spans, and it always advances the
+        ``fleet.*`` counters (:mod:`repro.core.telemetry`).
         """
         config_sets = list(config_sets)
         times_list = [arrival_times(a) for a in arrival_sets]
@@ -1351,50 +1368,68 @@ class FleetEngine:
                     raise KeyError(name)
 
         plane = self._plan_replay(template, collect_carry)["plane"]
-        if plane == "serial":
-            return self._run_many_serial(template, config_sets, times_list,
-                                         carry, collect_carry)
+        call = next(_CALLS)
+        instances = len(config_sets) * sum(len(t) for t in times_list)
+        telemetry.count(f"fleet.calls.{plane}")
+        telemetry.count("fleet.instances", instances)
+        with telemetry.span("fleet.run_many", call=call, plane=plane,
+                            candidates=len(config_sets),
+                            arrival_sets=len(times_list),
+                            instances=instances):
+            if plane == "serial":
+                return self._run_many_serial(template, config_sets,
+                                             times_list, carry,
+                                             collect_carry, call)
 
-        nodes, names, cpu, mem = self._candidate_arrays(template, config_sets)
-        if any(len(t) for t in times_list):
-            self._check_candidates_placeable(template, config_sets, cpu, mem)
-        if getattr(self.backend, "deterministic", False):
-            # ONE response-surface call for the whole C×V plane
-            runtimes, failed = self.backend.invoke_config_batch(
-                nodes, cpu, mem)
-            noise = None
-        else:
-            # paired replay-stream contract: noise-free surface plus
-            # ONE (instances, functions) noise draw shared by all
-            # candidates — a paired experiment across the batch
-            runtimes, failed = self.backend.config_surface(nodes, cpu, mem)
-            n_total = sum(len(t) for t in times_list)
-            noise = self.backend.replay_noise(n_total, len(nodes))
-        runtimes = np.asarray(runtimes, dtype=np.float64)
-        failed = np.asarray(failed, dtype=bool)
-        fstream = None
-        if self.faults is not None:
-            # paired fault-stream contract, mirroring replay_noise:
-            # ONE rng advance per plane, shared by every candidate and
-            # segmented per arrival set by instance-row offset — the
-            # same configuration in two candidate slots draws the same
-            # faults, so challenger validation is a paired experiment
-            fstream = self.faults.fault_stream(
-                sum(len(t) for t in times_list), len(nodes))
+            nodes, names, cpu, mem = self._candidate_arrays(template,
+                                                            config_sets)
+            if any(len(t) for t in times_list):
+                self._check_candidates_placeable(template, config_sets,
+                                                 cpu, mem)
+            with telemetry.span("fleet.surface", call=call):
+                if getattr(self.backend, "deterministic", False):
+                    # ONE response-surface call for the whole C×V plane
+                    runtimes, failed = self.backend.invoke_config_batch(
+                        nodes, cpu, mem)
+                    noise = None
+                else:
+                    # paired replay-stream contract: noise-free surface
+                    # plus ONE (instances, functions) noise draw shared by
+                    # all candidates — a paired experiment across the batch
+                    runtimes, failed = self.backend.config_surface(
+                        nodes, cpu, mem)
+                    n_total = sum(len(t) for t in times_list)
+                    noise = self.backend.replay_noise(n_total, len(nodes))
+            runtimes = np.asarray(runtimes, dtype=np.float64)
+            failed = np.asarray(failed, dtype=bool)
+            fstream = None
+            if self.faults is not None:
+                # paired fault-stream contract, mirroring replay_noise:
+                # ONE rng advance per plane, shared by every candidate
+                # and segmented per arrival set by instance-row offset —
+                # the same configuration in two candidate slots draws the
+                # same faults, so challenger validation is a paired
+                # experiment
+                fstream = self.faults.fault_stream(
+                    sum(len(t) for t in times_list), len(nodes))
 
-        if plane == "planned":
-            return self._run_many_planned(template, config_sets, times_list,
-                                          carry, collect_carry, names,
-                                          runtimes, failed, noise, fstream)
-        if plane == "constrained":
-            return self._run_many_constrained(template, config_sets,
+            if plane == "planned":
+                return self._run_many_planned(template, config_sets,
                                               times_list, carry,
-                                              collect_carry, names, cpu, mem,
+                                              collect_carry, names,
                                               runtimes, failed, noise,
-                                              fstream)
-        return self._run_many_vectorized(template, config_sets, times_list,
-                                         carry, names, cpu, mem,
-                                         runtimes, failed, noise)
+                                              fstream, call)
+            if plane == "constrained":
+                return self._run_many_constrained(template, config_sets,
+                                                  times_list, carry,
+                                                  collect_carry, names,
+                                                  cpu, mem, runtimes,
+                                                  failed, noise, fstream,
+                                                  call)
+            return self._run_many_vectorized(template, config_sets,
+                                             times_list, carry, names, cpu,
+                                             mem, runtimes, failed, noise,
+                                             call)
 
     def _plan_replay(self, template: Workflow, collect_carry: bool) -> dict:
         """Route a ``run_many`` call to its replay plane; shared with
@@ -1527,14 +1562,15 @@ class FleetEngine:
             self._check_placeable(wf)
 
     def _run_many_serial(self, template, config_sets, times_list,
-                         carry, collect_carry) -> List[FleetReport]:
+                         carry, collect_carry, call) -> List[FleetReport]:
         """Exact fallback: the looped-``run`` semantics, one fleet per
         (candidate, arrival set) cell."""
         out: List[FleetReport] = []
         for configs in config_sets:
             for times in times_list:
-                out.append(self._run_one_serial(template, configs, times,
-                                                carry, collect_carry))
+                with _cell_span(call, "serial"):
+                    out.append(self._run_one_serial(template, configs, times,
+                                                    carry, collect_carry))
         return out
 
     def _run_one_serial(self, template, configs, times, carry,
@@ -1548,7 +1584,7 @@ class FleetEngine:
 
     def _run_many_planned(self, template, config_sets, times_list, carry,
                           collect_carry, names, runtimes, failed,
-                          noise, fstream=None) -> List[FleetReport]:
+                          noise, fstream, call) -> List[FleetReport]:
         """Pricing model doesn't vectorize: replay every cell through
         per-instance workflow copies so custom scalar ``function_cost``
         sees real node objects — but drive the event loops off the
@@ -1561,10 +1597,11 @@ class FleetEngine:
         reports: List[FleetReport] = []
         for ci, configs in enumerate(config_sets):
             for si, times in enumerate(times_list):
-                reports.append(self._run_one_planned(
-                    template, configs, times, carry, collect_carry,
-                    names, runtimes[ci], failed[ci], noise, offsets[si],
-                    fstream))
+                with _cell_span(call, "planned"):
+                    reports.append(self._run_one_planned(
+                        template, configs, times, carry, collect_carry,
+                        names, runtimes[ci], failed[ci], noise, offsets[si],
+                        fstream))
         return reports
 
     def _run_one_planned(self, template, configs, times, carry,
@@ -1607,7 +1644,7 @@ class FleetEngine:
     def _run_many_constrained(self, template, config_sets, times_list,
                               carry, collect_carry, names, cpu, mem,
                               runtimes, failed, noise,
-                              fstream=None) -> List[FleetReport]:
+                              fstream, call) -> List[FleetReport]:
         """Finite-capacity / cold-start / carry-collecting cells: the
         exact scalar event loop, table-driven. The whole plane's
         runtimes come from the caller's ONE response-surface call and
@@ -1620,15 +1657,17 @@ class FleetEngine:
         offsets = [0]
         for c in counts:
             offsets.append(offsets[-1] + c)
-        if noise is None:
-            cost_plane = self.pricing.cost_batch(runtimes, cpu, mem)
-        else:
-            # failing invocations keep their deterministic thrash time
-            # (the same masking StochasticBackend._noise_batch applies)
-            rt_full = np.where(failed[:, None, :], runtimes[:, None, :],
-                               runtimes[:, None, :] * noise[None, :, :])
-            cost_full = self.pricing.cost_batch(rt_full, cpu[:, None, :],
-                                                mem[:, None, :])
+        with telemetry.span("fleet.price", call=call):
+            if noise is None:
+                cost_plane = self.pricing.cost_batch(runtimes, cpu, mem)
+            else:
+                # failing invocations keep their deterministic thrash
+                # time (the same masking StochasticBackend._noise_batch
+                # applies)
+                rt_full = np.where(failed[:, None, :], runtimes[:, None, :],
+                                   runtimes[:, None, :] * noise[None, :, :])
+                cost_full = self.pricing.cost_batch(
+                    rt_full, cpu[:, None, :], mem[:, None, :])
         reports: List[FleetReport] = []
         for ci in range(len(config_sets)):
             cpu_row = cpu[ci].tolist()
@@ -1647,10 +1686,11 @@ class FleetEngine:
                     seg = slice(offsets[si], offsets[si] + m)
                     rt_rows = rt_full[ci, seg].tolist()
                     cost_rows = cost_full[ci, seg].tolist()
-                reports.append(self._run_cell_table(
-                    template, times, carry, collect_carry, names, topo,
-                    cpu_row, mem_row, rt_rows, [failed_row] * m,
-                    cost_rows, fstream, offsets[si]))
+                with _cell_span(call, "constrained"):
+                    reports.append(self._run_cell_table(
+                        template, times, carry, collect_carry, names, topo,
+                        cpu_row, mem_row, rt_rows, [failed_row] * m,
+                        cost_rows, fstream, offsets[si]))
         return reports
 
     def _topology_tables(self, template, names):
@@ -1939,7 +1979,7 @@ class FleetEngine:
 
     def _run_many_vectorized(self, template, config_sets, times_list,
                              carry, names, cpu, mem, runtimes, failed,
-                             noise) -> List[FleetReport]:
+                             noise, call) -> List[FleetReport]:
         n_cand = len(config_sets)
         n_seeds = len(times_list)
         counts = [len(t) for t in times_list]
@@ -1955,9 +1995,10 @@ class FleetEngine:
         # exact event loop off the precomputed plan (no backend calls)
         for ci in np.flatnonzero(~finite):
             for si, times in enumerate(times_list):
-                reports[ci * n_seeds + si] = self._run_one_planned(
-                    template, config_sets[ci], times, carry, False,
-                    names, runtimes[ci], failed[ci], noise, offsets[si])
+                with _cell_span(call, "fast"):
+                    reports[ci * n_seeds + si] = self._run_one_planned(
+                        template, config_sets[ci], times, carry, False,
+                        names, runtimes[ci], failed[ci], noise, offsets[si])
         live = np.flatnonzero(finite)
         if not live.size:
             return reports
@@ -1974,120 +2015,130 @@ class FleetEngine:
         # float adds _FleetState.instance_costs performs. On the paired
         # stochastic plane the cost gains an instance axis (noise is
         # per (instance, function), shared across candidates).
-        if noise is None:
-            node_cost = self.pricing.cost_batch(rt, cpu[live], mem[live])
-            cand_cost = np.zeros(live.size)
-            for name in order:
-                cand_cost = cand_cost + node_cost[:, col[name]]
-            rt_col = lambda name: rt[:, col[name]][:, None]
-        else:
-            rt_eff = np.where(failed[live][:, None, :], rt[:, None, :],
-                              rt[:, None, :] * noise[None, :, :])
-            node_cost = self.pricing.cost_batch(
-                rt_eff, cpu[live][:, None, :], mem[live][:, None, :])
-            cand_cost = np.zeros((live.size, t_all.size))
-            for name in order:
-                cand_cost = cand_cost + node_cost[:, :, col[name]]
-            rt_col = lambda name: rt_eff[:, :, col[name]]
+        with telemetry.span("fleet.price", call=call):
+            if noise is None:
+                node_cost = self.pricing.cost_batch(rt, cpu[live], mem[live])
+                cand_cost = np.zeros(live.size)
+                for name in order:
+                    cand_cost = cand_cost + node_cost[:, col[name]]
+                rt_col = lambda name: rt[:, col[name]][:, None]
+            else:
+                rt_eff = np.where(failed[live][:, None, :], rt[:, None, :],
+                                  rt[:, None, :] * noise[None, :, :])
+                node_cost = self.pricing.cost_batch(
+                    rt_eff, cpu[live][:, None, :], mem[live][:, None, :])
+                cand_cost = np.zeros((live.size, t_all.size))
+                for name in order:
+                    cand_cost = cand_cost + node_cost[:, :, col[name]]
+                rt_col = lambda name: rt_eff[:, :, col[name]]
 
         # shared event skeleton: absolute finish of node v for every
         # (candidate, instance) — sources start at the arrival instant,
         # successors at the max of their predecessors' finishes, which
         # is exactly the event-loop recurrence (t + rt per hop)
         start_by_node: Dict[str, np.ndarray] = {}
-        if self.plane_backend == "jax" and noise is None:
-            inst_finish = self._sweep_jax(template, order, col, t_all, rt)
-        else:
-            finish_by_node: Dict[str, np.ndarray] = {}
-            for name in order:
-                preds = template.predecessors(name)
-                if preds:
-                    start = finish_by_node[preds[0]]
-                    for p in preds[1:]:
-                        start = np.maximum(start, finish_by_node[p])
-                else:
-                    start = t_all[None, :]
-                if noise is not None:
-                    # start order drives the busy ledger below: the
-                    # scalar loop admits (and accumulates) in
-                    # start-event order, which per-instance noise can
-                    # decouple from arrival order
-                    start_by_node[name] = np.broadcast_to(
-                        start, (live.size, t_all.size))
-                finish_by_node[name] = start + rt_col(name)
-            inst_finish = None
-            for arr in finish_by_node.values():
-                inst_finish = arr if inst_finish is None \
-                    else np.maximum(inst_finish, arr)
-
-        pfq = {f"{template.identity}/{name}": 0.0 for name in names}
-        busy = carry.busy if carry is not None else []
-        for si, times in enumerate(times_list):
-            m = counts[si]
-            seg = slice(offsets[si], offsets[si] + m)
-            for k, ci in enumerate(live):
-                idx = int(ci) * n_seeds + si
-                if m == 0:
-                    reports[idx] = self._empty_report()
-                    continue
-                if m == 1:
-                    # a fleet of one takes ``run``'s degenerate fast
-                    # path, whose float associations (relative
-                    # longest-path shifted by the arrival, cost in
-                    # node-insertion order) differ from the absolute-
-                    # time plane in the last bits — replay the cell off
-                    # the plan to keep the bit-identity contract
-                    reports[idx] = self._run_one_planned(
-                        template, config_sets[ci], times, carry, False,
-                        names, runtimes[ci], failed[ci], noise,
-                        offsets[si])
-                    continue
-                t0 = float(times.min())
-                t_last = float(inst_finish[k, seg].max())
-                # carried-over reservations release inside this run and
-                # can be its last event (capacity itself never binds)
-                for f, _, _ in busy:
-                    if f > t0 and f > t_last:
-                        t_last = float(f)
-                # per-fn busy ledger: the scalar loop's left-to-right
-                # accumulation in admission (= start-event) order. With
-                # noise off every instance contributes the same value,
-                # so repeated addition reproduces any admission order
-                # bit-for-bit; with noise on, instances are summed in
-                # start-time order (stable on ties).
-                fn_busy: Dict[str, float] = {}
-                for name in names:
-                    if noise is None:
-                        val = float(rt[k, col[name]])
-                        acc = 0.0
-                        for _ in range(m):
-                            acc += val
+        with telemetry.span("fleet.sweep", call=call,
+                            cells=int(live.size) * n_seeds):
+            if self.plane_backend == "jax" and noise is None:
+                inst_finish = self._sweep_jax(template, order, col, t_all,
+                                              rt, call)
+            else:
+                finish_by_node: Dict[str, np.ndarray] = {}
+                for name in order:
+                    preds = template.predecessors(name)
+                    if preds:
+                        start = finish_by_node[preds[0]]
+                        for p in preds[1:]:
+                            start = np.maximum(start, finish_by_node[p])
                     else:
-                        vals = rt_eff[k, seg, col[name]]
-                        starts = start_by_node[name][k, seg]
-                        acc = 0.0
-                        for x in vals[np.argsort(starts,
-                                                 kind="stable")].tolist():
-                            acc += x
-                    fn_busy[f"{template.identity}/{name}"] = acc
-                zeros = np.zeros(m)
-                cost = (np.full(m, cand_cost[k]) if noise is None
-                        else cand_cost[k, seg].copy())
-                reports[idx] = FleetReport.from_arrays(
-                    arrival=np.array(times, dtype=np.float64),
-                    finish=inst_finish[k, seg].copy(),
-                    e2e=inst_finish[k, seg] - times,
-                    queue_delay=zeros, cold_delay=zeros.copy(),
-                    cost=cost,
-                    failed=np.full(m, bool(cand_failed[k]), dtype=bool),
-                    makespan=max(t_last - t0, 0.0),
-                    cpu_utilization=0.0, mem_utilization=0.0,
-                    queue_delay_by_function=dict(pfq),
-                    busy_by_function=fn_busy,
-                    tenants=[template.identity] * m)
+                        start = t_all[None, :]
+                    if noise is not None:
+                        # start order drives the busy ledger below: the
+                        # scalar loop admits (and accumulates) in
+                        # start-event order, which per-instance noise can
+                        # decouple from arrival order
+                        start_by_node[name] = np.broadcast_to(
+                            start, (live.size, t_all.size))
+                    finish_by_node[name] = start + rt_col(name)
+                inst_finish = None
+                for arr in finish_by_node.values():
+                    inst_finish = arr if inst_finish is None \
+                        else np.maximum(inst_finish, arr)
+
+        with telemetry.span("fleet.assemble", call=call):
+            pfq = {f"{template.identity}/{name}": 0.0 for name in names}
+            busy = carry.busy if carry is not None else []
+            for si, times in enumerate(times_list):
+                m = counts[si]
+                seg = slice(offsets[si], offsets[si] + m)
+                for k, ci in enumerate(live):
+                    idx = int(ci) * n_seeds + si
+                    if m == 0:
+                        reports[idx] = self._empty_report()
+                        continue
+                    if m == 1:
+                        # a fleet of one takes ``run``'s degenerate fast
+                        # path, whose float associations (relative
+                        # longest-path shifted by the arrival, cost in
+                        # node-insertion order) differ from the absolute-
+                        # time plane in the last bits — replay the cell off
+                        # the plan to keep the bit-identity contract
+                        with _cell_span(call, "fast"):
+                            reports[idx] = self._run_one_planned(
+                                template, config_sets[ci], times, carry,
+                                False, names, runtimes[ci], failed[ci],
+                                noise, offsets[si])
+                        continue
+                    t0 = float(times.min())
+                    t_last = float(inst_finish[k, seg].max())
+                    # carried-over reservations release inside this run and
+                    # can be its last event (capacity itself never binds)
+                    for f, _, _ in busy:
+                        if f > t0 and f > t_last:
+                            t_last = float(f)
+                    # per-fn busy ledger: the scalar loop's left-to-right
+                    # accumulation in admission (= start-event) order. With
+                    # noise off every instance contributes the same value,
+                    # so repeated addition reproduces any admission order
+                    # bit-for-bit; with noise on, instances are summed in
+                    # start-time order (stable on ties).
+                    fn_busy: Dict[str, float] = {}
+                    with telemetry.span("fleet.ledger", call=call,
+                                        cand=int(ci)):
+                        for name in names:
+                            if noise is None:
+                                val = float(rt[k, col[name]])
+                                acc = 0.0
+                                for _ in range(m):
+                                    acc += val
+                            else:
+                                vals = rt_eff[k, seg, col[name]]
+                                starts = start_by_node[name][k, seg]
+                                order_k = np.argsort(starts, kind="stable")
+                                acc = 0.0
+                                for x in vals[order_k].tolist():
+                                    acc += x
+                            fn_busy[f"{template.identity}/{name}"] = acc
+                    telemetry.count("fleet.cells.swept")
+                    zeros = np.zeros(m)
+                    cost = (np.full(m, cand_cost[k]) if noise is None
+                            else cand_cost[k, seg].copy())
+                    reports[idx] = FleetReport.from_arrays(
+                        arrival=np.array(times, dtype=np.float64),
+                        finish=inst_finish[k, seg].copy(),
+                        e2e=inst_finish[k, seg] - times,
+                        queue_delay=zeros, cold_delay=zeros.copy(),
+                        cost=cost,
+                        failed=np.full(m, bool(cand_failed[k]), dtype=bool),
+                        makespan=max(t_last - t0, 0.0),
+                        cpu_utilization=0.0, mem_utilization=0.0,
+                        queue_delay_by_function=dict(pfq),
+                        busy_by_function=fn_busy,
+                        tenants=[template.identity] * m)
         return reports
 
-    def _sweep_jax(self, template, order, col, t_all, rt) -> np.ndarray:
+    def _sweep_jax(self, template, order, col, t_all, rt,
+                   call) -> np.ndarray:
         """The fast plane's longest-path sweep as a jitted ``lax.scan``
         over topological ranks (x64): all C×N×V finish times advance as
         one device program — the fleet-step end state this repo aims
@@ -2108,10 +2159,15 @@ class FleetEngine:
             for j, p in enumerate(template.predecessors(name)):
                 pred_idx[k, j] = col[p]
                 pred_mask[k, j] = True
+        shape = (rt.shape[0], t_all.shape[0], rt.shape[1], max_p)
+        if shape not in _SWEEP_SHAPES:
+            _SWEEP_SHAPES.add(shape)
+            telemetry.count("fleet.sweep.shapes")
         with jax.enable_x64(True):
             fin = sweep(t_all, rt, order_idx, pred_idx, pred_mask)
             self.sweep_device = fin.device
-            return np.asarray(fin)
+            with telemetry.span("fleet.fetch", call=call):
+                return np.asarray(fin)
 
     # -- internals -----------------------------------------------------
     def _run_degenerate(self, wf: Workflow, arrival: float) -> FleetReport:

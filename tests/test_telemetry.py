@@ -1,0 +1,246 @@
+"""The replay path's spans and counters (``repro.core.telemetry``).
+
+Under a JAX profiler trace on the CPU, each ``run_many`` records one
+``fleet.run_many`` span whose phases nest inside it and carry its
+``call``; the counters name the plane ``batch_eligibility`` reports;
+tracing changes no bit of any report; and a numpy-only deployment never
+loads jax.
+"""
+import glob
+import os
+import shutil
+import subprocess
+import sys
+import tempfile
+import warnings
+
+import numpy as np
+import pytest
+
+from repro.core import telemetry
+from repro.core.backend import CallableBackend
+from repro.core.cost import PricingModel
+from repro.core.engine import (ClusterModel, ColdStartModel, FleetEngine,
+                               PoissonArrivals)
+from repro.core.resources import ResourceConfig
+from repro.serverless.generator import layered_workflow
+from repro.serverless.platform import SimulatedPlatform
+
+CONSTRAINED_KW = dict(cluster=ClusterModel(total_cpu=12.0,
+                                           total_mem_mb=16384.0),
+                      cold_start=ColdStartModel(delay_s=1.0,
+                                                keep_alive_s=30.0))
+
+
+class _ScalarPricing(PricingModel):
+    """The same prices through a scalar override with no matching
+    ``cost_batch``: routes replays onto the planned plane."""
+
+    def function_cost(self, runtime_s, config):
+        return super().function_cost(runtime_s, config)
+
+
+def _engine(plane: str) -> FleetEngine:
+    env = SimulatedPlatform().environment()
+    if plane == "fast":
+        return FleetEngine(env.backend, pricing=env.pricing,
+                           plane_backend="jax")
+    if plane == "constrained":
+        return FleetEngine(env.backend, pricing=env.pricing,
+                           **CONSTRAINED_KW)
+    if plane == "planned":
+        return FleetEngine(env.backend, pricing=_ScalarPricing())
+    return FleetEngine(CallableBackend(lambda node: node.config.cpu * 0.1),
+                       pricing=env.pricing)
+
+
+def _inputs(n_cand=3, n_seeds=2, n=6):
+    template = layered_workflow(8, n_layers=3, seed=14)
+    rng = np.random.default_rng(5)
+    cands = [{node.name: ResourceConfig(cpu=float(rng.uniform(1.0, 8.0)),
+                                        mem=float(rng.uniform(1024.0,
+                                                              8192.0)))
+              for node in template} for _ in range(n_cand)]
+    seeds = [PoissonArrivals(0.25, n, seed=s).times()
+             for s in range(n_seeds)]
+    return template, cands, seeds
+
+
+def _traced(fn):
+    """Run ``fn`` under a profiler trace; returns its result and the
+    trace's ``fleet.*`` spans as (start, end, name, stats)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    log_dir = tempfile.mkdtemp()
+    try:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        try:
+            out = fn()
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+        data = ProfileData.from_file(path)
+        spans = []
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", DeprecationWarning)
+            for plane in data.planes:
+                for line in plane.lines:
+                    for e in line.events:
+                        if e.name.startswith("fleet."):
+                            a = float(e.start_ns)
+                            stats = {k: v for k, v in e.stats}
+                            spans.append((a, a + float(e.duration_ns),
+                                          e.name, stats))
+    finally:
+        shutil.rmtree(log_dir, ignore_errors=True)
+    return out, spans
+
+
+def _inside(inner, outer) -> bool:
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+@pytest.mark.parametrize("plane", ["fast", "constrained"])
+def test_each_run_many_is_one_span_with_its_phases_inside(plane):
+    template, cands, seeds = _inputs()
+    engine = _engine(plane)
+    engine.run_many(template, cands, seeds)          # compile outside
+
+    def two_calls():
+        engine.run_many(template, cands, seeds)
+        engine.run_many(template, cands, seeds)
+
+    _, spans = _traced(two_calls)
+    roots = [s for s in spans if s[2] == "fleet.run_many"]
+    assert len(roots) == 2
+    assert roots[0][3]["call"] != roots[1][3]["call"]
+    for root in roots:
+        assert root[3]["plane"] == plane
+        assert root[3]["candidates"] == 3 and root[3]["arrival_sets"] == 2
+        assert root[3]["instances"] == 3 * 2 * 6
+        kids = [s for s in spans if s[2] != "fleet.run_many"
+                and s[3]["call"] == root[3]["call"]]
+        assert kids and all(_inside(k, root) for k in kids)
+        names = [k[2] for k in kids]
+        assert names.count("fleet.surface") == 1
+        assert names.count("fleet.price") == 1
+        if plane == "fast":
+            assert names.count("fleet.sweep") == 1
+            assert names.count("fleet.fetch") == 1
+            assert names.count("fleet.assemble") == 1
+            assert names.count("fleet.ledger") == 3 * 2
+            sweep = next(k for k in kids if k[2] == "fleet.sweep")
+            assert sweep[3]["cells"] == 3 * 2
+            assemble = next(k for k in kids if k[2] == "fleet.assemble")
+            for k in kids:
+                if k[2] == "fleet.fetch":
+                    assert _inside(k, sweep)
+                if k[2] == "fleet.ledger":
+                    assert _inside(k, assemble)
+            assert sorted(k[3]["cand"] for k in kids
+                          if k[2] == "fleet.ledger") == [0, 0, 1, 1, 2, 2]
+        else:
+            cells = [k for k in kids if k[2] == "fleet.cell"]
+            assert len(cells) == 3 * 2
+            assert {k[3]["plane"] for k in cells} == {"constrained"}
+
+
+@pytest.mark.parametrize("plane", ["fast", "constrained", "planned",
+                                   "serial"])
+def test_counters_name_the_plane_batch_eligibility_reports(plane):
+    template, cands, seeds = _inputs()
+    engine = _engine(plane)
+    assert engine.batch_eligibility(template, cands)["plane"] == plane
+    before = telemetry.counters()
+    engine.run_many(template, cands, seeds)
+    after = telemetry.counters()
+    delta = {k: v - before.get(k, 0) for k, v in after.items()
+             if v != before.get(k, 0)}
+    assert delta.pop(f"fleet.calls.{plane}") == 1
+    assert delta.pop("fleet.instances") == 3 * 2 * 6
+    cells = 3 * 2
+    if plane == "fast":
+        assert delta.pop("fleet.cells.swept") == cells
+    else:
+        assert delta.pop("fleet.cells.per_cell") == cells
+    # the sweep's shape may have been seen by an earlier test here
+    assert delta.pop("fleet.sweep.shapes", 0) in (0, 1)
+    assert delta == {}
+
+
+def test_a_new_sweep_shape_is_counted_once():
+    # 37 instances in one arrival set: a shape no other test sweeps
+    template, cands, seeds = _inputs(n_seeds=1, n=37)
+    engine = _engine("fast")
+    before = telemetry.counters().get("fleet.sweep.shapes", 0)
+    engine.run_many(template, cands, seeds)
+    engine.run_many(template, cands, seeds)
+    assert telemetry.counters()["fleet.sweep.shapes"] == before + 1
+
+
+@pytest.mark.parametrize("plane", ["fast", "constrained"])
+def test_reports_are_bit_identical_with_the_profiler_on(plane):
+    template, cands, seeds = _inputs()
+    off = _engine(plane).run_many(template, cands, seeds)
+    on, spans = _traced(lambda: _engine(plane).run_many(template, cands,
+                                                        seeds))
+    assert spans
+    assert len(on) == len(off)
+    for a, b in zip(on, off):
+        for field in ("arrivals", "finishes", "latencies", "queue_delays",
+                      "cold_delays", "costs", "failed_mask"):
+            assert np.array_equal(getattr(a, field), getattr(b, field))
+        assert a.makespan == b.makespan and a.p99 == b.p99
+        assert a.busy_by_function == b.busy_by_function
+        assert a.total_cost == b.total_cost
+
+
+def test_a_collection_is_a_span_while_tracing():
+    import gc
+
+    import jax
+    from jax.profiler import ProfileData
+
+    log_dir = tempfile.mkdtemp()
+    try:
+        jax.profiler.start_trace(log_dir)
+        gc.collect(1)
+        jax.profiler.stop_trace()
+        path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+        data = ProfileData.from_file(path)
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", DeprecationWarning)
+            stats = [{k: v for k, v in e.stats} for p in data.planes
+                     for line in p.lines for e in line.events
+                     if e.name == "py.gc"]
+    finally:
+        shutil.rmtree(log_dir, ignore_errors=True)
+    assert {"generation": 1} in stats
+
+
+def test_numpy_plane_never_loads_jax():
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = (
+        "import sys, gc\n"
+        "import repro.core\n"
+        "from repro.core import telemetry\n"
+        "from repro.core.engine import FleetEngine, PoissonArrivals\n"
+        "from repro.serverless.generator import chain_workflow\n"
+        "from repro.serverless.platform import SimulatedPlatform\n"
+        "env = SimulatedPlatform().environment()\n"
+        "wf = chain_workflow(4, seed=1)\n"
+        "engine = FleetEngine(env.backend, pricing=env.pricing)\n"
+        "times = PoissonArrivals(0.5, 8, seed=0).times()\n"
+        "reports = engine.run_many(wf, [{}, {}], [times])\n"
+        "gc.collect()\n"
+        "assert len(reports) == 2\n"
+        "assert telemetry.counters()['fleet.calls.fast'] == 1\n"
+        "print('jax' in sys.modules)\n")
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(src))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "False"
