@@ -971,6 +971,16 @@ def _reduce_costs(cost_items: List[List[Tuple[int, float]]],
     return out
 
 
+def _fold_rows(rows: np.ndarray) -> List[float]:
+    """Each row of ``rows`` summed left to right from 0.0, as Python
+    floats: the scalar event loop's ``acc += x`` over the same values in
+    the same order, bit for bit. ``np.add.accumulate`` adds strictly in
+    sequence; ``np.sum`` (pairwise), ``math.fsum`` (exact) and ``m * x``
+    each round differently."""
+    # + 0.0: the loop starts from +0.0, so a row of -0.0 sums to +0.0
+    return (np.add.accumulate(rows, axis=1)[:, -1] + 0.0).tolist()
+
+
 class _PlannedBackend(BaseBackend):
     """Replays a precomputed ``(runtime, failed)`` plan keyed by node
     identity. The planned/per-cell replay paths use it to drive the
@@ -2066,7 +2076,8 @@ class FleetEngine:
                         else np.maximum(inst_finish, arr)
 
         with telemetry.span("fleet.assemble", call=call):
-            pfq = {f"{template.identity}/{name}": 0.0 for name in names}
+            fn_keys = [f"{template.identity}/{name}" for name in names]
+            pfq = dict.fromkeys(fn_keys, 0.0)
             busy = carry.busy if carry is not None else []
             for si, times in enumerate(times_list):
                 m = counts[si]
@@ -2097,28 +2108,28 @@ class FleetEngine:
                         if f > t0 and f > t_last:
                             t_last = float(f)
                     # per-fn busy ledger: the scalar loop's left-to-right
-                    # accumulation in admission (= start-event) order. With
-                    # noise off every instance contributes the same value,
-                    # so repeated addition reproduces any admission order
-                    # bit-for-bit; with noise on, instances are summed in
-                    # start-time order (stable on ties).
-                    fn_busy: Dict[str, float] = {}
+                    # accumulation in admission (= start-event) order, one
+                    # row per function. With noise off every instance
+                    # contributes the same value, so the row repeats it and
+                    # any admission order gives the same bits; with noise
+                    # on, instances are summed in start-time order (stable
+                    # on ties).
                     with telemetry.span("fleet.ledger", call=call,
                                         cand=int(ci)):
-                        for name in names:
-                            if noise is None:
-                                val = float(rt[k, col[name]])
-                                acc = 0.0
-                                for _ in range(m):
-                                    acc += val
-                            else:
-                                vals = rt_eff[k, seg, col[name]]
+                        if noise is None:
+                            rows = np.broadcast_to(rt[k][:, None],
+                                                   (len(names), m))
+                            telemetry.count("fleet.ledger.rows.repeat",
+                                            len(names))
+                        else:
+                            rows = np.empty((len(names), m))
+                            for v, name in enumerate(names):
                                 starts = start_by_node[name][k, seg]
-                                order_k = np.argsort(starts, kind="stable")
-                                acc = 0.0
-                                for x in vals[order_k].tolist():
-                                    acc += x
-                            fn_busy[f"{template.identity}/{name}"] = acc
+                                rows[v] = rt_eff[k, seg, v][
+                                    np.argsort(starts, kind="stable")]
+                            telemetry.count("fleet.ledger.rows.ordered",
+                                            len(names))
+                        fn_busy = dict(zip(fn_keys, _fold_rows(rows)))
                     telemetry.count("fleet.cells.swept")
                     zeros = np.zeros(m)
                     cost = (np.full(m, cand_cost[k]) if noise is None

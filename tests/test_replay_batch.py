@@ -19,7 +19,7 @@ import pytest
 from repro.core.backend import CallableBackend
 from repro.core.cost import PricingModel
 from repro.core.engine import (ClusterModel, ColdStartModel, FleetCarry,
-                               FleetEngine, PoissonArrivals)
+                               FleetEngine, PoissonArrivals, _fold_rows)
 from repro.core.resources import ResourceConfig
 from repro.serverless.generator import (chain_workflow, diamond_workflow,
                                         fan_workflow, layered_workflow)
@@ -75,6 +75,7 @@ def assert_reports_identical(got, want):
     assert np.array_equal(got.failed_mask, want.failed_mask)
     assert got.makespan == want.makespan
     assert got.queue_delay_by_function == want.queue_delay_by_function
+    assert got.busy_by_function == want.busy_by_function
     assert got.total_cost == want.total_cost
     assert got.total_queue_delay == want.total_queue_delay
     assert got.p50 == want.p50 and got.p99 == want.p99
@@ -657,6 +658,42 @@ def test_jax_plane_backend_matches_numpy_bitwise():
     # the sweep really ran as a jax program, on JAX's default device
     import jax
     assert jax_engine.sweep_device.platform == jax.default_backend()
+
+
+#: values whose running sums round half to even, reach the subnormals,
+#: stay huge, or start from -0.0 (the scalar loop starts from +0.0)
+FOLD_VALUES = [0.1, 3.0000000000000004, 2.0 ** -1074, 1e300, -0.0]
+
+
+@pytest.mark.parametrize("m", [2, 3, 4096, 100_000])
+@pytest.mark.parametrize("branch", ["repeat", "ordered"])
+def test_busy_ledger_fold_matches_the_python_loop_bit_for_bit(branch, m):
+    """The fast plane's busy-ledger fold against the scalar event loop's
+    ``acc += x``: one value repeated m times (noise off), or per-instance
+    values admitted in start order, stable on ties (noise on)."""
+    rng = np.random.default_rng(m)
+    if branch == "repeat":
+        rows = np.broadcast_to(np.array(FOLD_VALUES)[:, None],
+                               (len(FOLD_VALUES), m))
+        admitted = [[x] * m for x in FOLD_VALUES]
+    else:
+        # few distinct start times, shuffled: most instances tie
+        starts = rng.integers(0, max(m // 8, 2), size=m).astype(float)
+        vals = np.array([np.where(rng.random(m) < 0.5, x,
+                                  rng.uniform(0.0, 10.0, m))
+                         for x in FOLD_VALUES])
+        rows = vals[:, np.argsort(starts, kind="stable")]
+        order = sorted(range(m), key=starts.__getitem__)  # stable
+        admitted = [[float(row[i]) for i in order] for row in vals]
+    want = []
+    for xs in admitted:
+        acc = 0.0
+        for x in xs:
+            acc += x
+        want.append(acc)
+    got = _fold_rows(rows)
+    assert all(type(x) is float for x in got)
+    assert [x.hex() for x in got] == [x.hex() for x in want]
 
 
 def test_jax_plane_names_numpy_sweep_under_replay_noise():

@@ -24,7 +24,7 @@ from repro.core.engine import (ClusterModel, ColdStartModel, FleetEngine,
                                PoissonArrivals)
 from repro.core.resources import ResourceConfig
 from repro.serverless.generator import layered_workflow
-from repro.serverless.platform import SimulatedPlatform
+from repro.serverless.platform import SimulatedPlatform, StochasticBackend
 
 CONSTRAINED_KW = dict(cluster=ClusterModel(total_cpu=12.0,
                                            total_mem_mb=16384.0),
@@ -50,6 +50,10 @@ def _engine(plane: str) -> FleetEngine:
                            **CONSTRAINED_KW)
     if plane == "planned":
         return FleetEngine(env.backend, pricing=_ScalarPricing())
+    if plane == "stochastic":
+        # replay noise: the fast plane with the numpy sweep
+        return FleetEngine(StochasticBackend(noise_sigma=0.05, seed=3),
+                           pricing=env.pricing, plane_backend="jax")
     return FleetEngine(CallableBackend(lambda node: node.config.cpu * 0.1),
                        pricing=env.pricing)
 
@@ -149,21 +153,27 @@ def test_each_run_many_is_one_span_with_its_phases_inside(plane):
 
 
 @pytest.mark.parametrize("plane", ["fast", "constrained", "planned",
-                                   "serial"])
+                                   "serial", "stochastic"])
 def test_counters_name_the_plane_batch_eligibility_reports(plane):
     template, cands, seeds = _inputs()
     engine = _engine(plane)
-    assert engine.batch_eligibility(template, cands)["plane"] == plane
+    routed = "fast" if plane == "stochastic" else plane
+    assert engine.batch_eligibility(template, cands)["plane"] == routed
     before = telemetry.counters()
     engine.run_many(template, cands, seeds)
     after = telemetry.counters()
     delta = {k: v - before.get(k, 0) for k, v in after.items()
              if v != before.get(k, 0)}
-    assert delta.pop(f"fleet.calls.{plane}") == 1
+    assert delta.pop(f"fleet.calls.{routed}") == 1
     assert delta.pop("fleet.instances") == 3 * 2 * 6
     cells = 3 * 2
-    if plane == "fast":
+    if routed == "fast":
         assert delta.pop("fleet.cells.swept") == cells
+        # one busy-ledger row per function of each swept cell, in the
+        # fold its replay takes: repeated runtimes without noise,
+        # start-ordered ones with it
+        fold = "ordered" if plane == "stochastic" else "repeat"
+        assert delta.pop(f"fleet.ledger.rows.{fold}") == cells * 8
     else:
         assert delta.pop("fleet.cells.per_cell") == cells
     # the sweep's shape may have been seen by an earlier test here
