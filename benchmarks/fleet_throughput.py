@@ -14,7 +14,7 @@ constrained plane), and reports
   * C×S batched-replay speedup over the scalar loop for both planes,
     with every cell verified bit-identical,
   * an informational ``jax_scan_fleet`` row timing the jitted
-    ``lax.scan`` sweep against the numpy sweep.
+    rank-by-rank sweep against the numpy sweep.
 
 Emits ``BENCH_fleet.json`` under artifacts/bench/ so regressions in
 the engine hot path surface in CI diffs. ``--smoke`` gates the
@@ -184,7 +184,7 @@ def _run_jax_scan_case(n_candidates: int = REPLAY_C,
                        n_seeds: int = REPLAY_S,
                        n_instances: int = REPLAY_N):
     """Informational row: the fast plane's longest-path sweep as a
-    jitted ``lax.scan`` (``FleetEngine(plane_backend="jax")``) vs the
+    jitted program (``FleetEngine(plane_backend="jax")``) vs the
     numpy sweep, bit-identity included."""
     template, candidates, seeds = _replay_grid(n_candidates, n_seeds,
                                                n_instances)

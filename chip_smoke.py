@@ -104,7 +104,7 @@ def _max_rel_dev(got: np.ndarray, want: np.ndarray) -> float:
 
 
 def phase_fleet(sizes: Sizes) -> dict:
-    """A: the jitted lax.scan replay sweep on the device, against the
+    """A: the jitted rank-by-rank replay sweep on the device, against the
     numpy plane, then the campaign entry point."""
     import jax
 

@@ -25,8 +25,8 @@ instances against a cluster model:
     one vectorized evaluation: ONE ``invoke_config_batch``
     response-surface call and ONE ``cost_batch`` pricing expression for
     the whole plane, then either a candidate-vectorized longest-path
-    sweep (contention-free fleets; optionally a jitted ``lax.scan``
-    via ``plane_backend="jax"``) or table-driven replays of the exact
+    sweep (contention-free fleets; optionally a jitted sweep by
+    topological rank via ``plane_backend="jax"``) or table-driven replays of the exact
     event loop (finite capacity, cold starts, carry collection) —
     bit-identical to the looped scalar path either way. Stochastic
     backends join the plane through a paired replay-noise stream; only
@@ -68,7 +68,8 @@ from repro.core import telemetry
 from repro.core.backend import BaseBackend, RuntimeBackend, as_backend
 from repro.core.cost import DEFAULT_PRICING, PricingModel
 from repro.core.dag import Workflow
-from repro.core.resources import ResourceConfig
+from repro.core.resources import (CPU_MAX, CPU_MIN, CPU_STEP, MEM_MAX_MB,
+                                  MEM_MIN_MB, MEM_STEP_MB, ResourceConfig)
 
 
 # --------------------------------------------------------------------------
@@ -971,6 +972,14 @@ def _reduce_costs(cost_items: List[List[Tuple[int, float]]],
     return out
 
 
+def _quantized(x: np.ndarray, lo: float, hi: float,
+               step: float) -> np.ndarray:
+    """``quantize_cpu``/``quantize_mem`` over an array, bit for bit:
+    the same clamp, division and product, and ``np.round`` rounds
+    halves to even as ``round`` does."""
+    return np.round(np.minimum(np.maximum(x, lo), hi) / step) * step
+
+
 def _fold_rows(rows: np.ndarray) -> List[float]:
     """Each row of ``rows`` summed left to right from 0.0, as Python
     floats: the scalar event loop's ``acc += x`` over the same values in
@@ -1005,50 +1014,86 @@ class _PlannedBackend(BaseBackend):
 
 #: lazily-built jitted sweep — see _jax_sweep_fn
 _JAX_SWEEP = None
-#: (C', N, V, max_preds) signatures the jitted sweep has been given: a
-#: new one is a trace plus a compile or a persistent-cache load
+#: (C', N, ((width, in-degree) per rank)) signatures the jitted sweep
+#: has been given: a new one is a trace plus a compile or a
+#: persistent-cache load
 _SWEEP_SHAPES: set = set()
 #: sequence numbers of ``run_many`` calls, the ``call`` stat of their spans
 _CALLS = itertools.count(1)
 
 
 def _jax_sweep_fn():
-    """Build (once) the jitted ``lax.scan`` fleet step behind
-    ``FleetEngine(plane_backend="jax")``: one scan iteration per
-    topological rank advances the (candidates, instances, nodes)
-    finish-time tensor as a single device program. Import of jax is
-    deferred to first use so numpy-only deployments never pay for it."""
+    """Build (once) the jitted fleet step behind
+    ``FleetEngine(plane_backend="jax")``: one step per topological rank
+    sets that rank's columns of the (candidates, instances, nodes)
+    finish-time tensor, all in a single device program. Columns are in
+    rank order; ``preds[r]`` holds the columns of rank r's predecessors,
+    padded to the rank's largest in-degree (``masks[r]`` marks the live
+    slots). Rank 0 is the sources, which gather nothing. Import of jax
+    is deferred to first use so numpy-only deployments never pay for
+    it."""
     global _JAX_SWEEP
     if _JAX_SWEEP is None:
         import jax
         import jax.numpy as jnp
-        from jax import lax
 
         @jax.jit
-        def sweep(t_all, rt, order_idx, pred_idx, pred_mask):
-            finish0 = jnp.zeros((rt.shape[0], t_all.shape[0], rt.shape[1]),
-                                dtype=rt.dtype)
-
-            def step(fin, x):
-                v, pidx, pmask, rt_v = x
-                # a source has no live predecessor: its start is the
-                # arrival instant, everything else max-reduces over
-                # its predecessors' finishes — the same recurrence the
-                # numpy sweep runs per node
-                pf = jnp.where(pmask[None, None, :],
-                               fin[:, :, pidx], -jnp.inf)
-                start = jnp.max(pf, axis=-1)
-                start = jnp.where(jnp.isneginf(start),
-                                  t_all[None, :], start)
-                return fin.at[:, :, v].set(start + rt_v[:, None]), None
-
-            fin, _ = lax.scan(step, finish0,
-                              (order_idx, pred_idx, pred_mask,
-                               rt[:, order_idx].T))
+        def sweep(t_all, rt, preds, masks):
+            c, n = rt.shape[0], t_all.shape[0]
+            fin = jnp.zeros((c, n, rt.shape[1]), dtype=rt.dtype)
+            lo = 0
+            # the ranks are few and differ in shape: unrolled, each
+            # gathers only its own predecessors
+            for pidx, pmask in zip(preds, masks):
+                w = pidx.shape[0]
+                if pidx.shape[1] == 0:
+                    # a source starts at the arrival instant
+                    start = jnp.broadcast_to(t_all[None, :, None], (c, n, w))
+                else:
+                    # everything else starts at the max of its
+                    # predecessors' finishes, slot by slot — the same
+                    # recurrence the numpy sweep runs per node (slot 0
+                    # always holds an edge; padding reads -inf)
+                    start = fin[:, :, pidx[:, 0]]
+                    for j in range(1, pidx.shape[1]):
+                        start = jnp.maximum(start, jnp.where(
+                            pmask[None, None, :, j], fin[:, :, pidx[:, j]],
+                            -jnp.inf))
+                fin = fin.at[:, :, lo:lo + w].set(
+                    start + rt[:, None, lo:lo + w])
+                lo += w
             return fin.max(axis=2)
 
         _JAX_SWEEP = sweep
     return _JAX_SWEEP
+
+
+def _rank_tables(template, order):
+    """The jitted sweep's topology: the functions in rank order (rank =
+    longest hop count from a source; topological order within a rank)
+    and, per rank, the (width, in-degree) tables of their predecessors'
+    positions in that order and of the live slots."""
+    rank: Dict[str, int] = {}
+    for name in order:
+        rank[name] = 1 + max((rank[p] for p in template.predecessors(name)),
+                             default=-1)
+    by_rank: List[List[str]] = [[] for _ in range(max(rank.values()) + 1)]
+    for name in order:
+        by_rank[rank[name]].append(name)
+    ranked = [name for names in by_rank for name in names]
+    pos = {name: i for i, name in enumerate(ranked)}
+    preds, masks = [], []
+    for names in by_rank:
+        width = max(len(template.predecessors(n)) for n in names)
+        idx = np.zeros((len(names), width), dtype=np.int32)
+        live = np.zeros((len(names), width), dtype=bool)
+        for k, name in enumerate(names):
+            for j, p in enumerate(template.predecessors(name)):
+                idx[k, j] = pos[p]
+                live[k, j] = True
+        preds.append(idx)
+        masks.append(live)
+    return ranked, tuple(preds), tuple(masks)
 
 
 def _cell_span(call: int, plane: str):
@@ -1100,13 +1145,16 @@ class FleetEngine:
                 f"{plane_backend!r}")
         #: which array engine evaluates the contention-free replay
         #: plane's longest-path sweep; ``"jax"`` runs a jitted
-        #: ``lax.scan`` over topological ranks (x64) instead of the
-        #: numpy loop — same recurrence, device-compiled
+        #: program, one step per topological rank (x64), instead of
+        #: the numpy loop — same recurrence, device-compiled
         self.plane_backend = plane_backend
         #: the device the last jitted sweep's output lives on (``None``
         #: until one ran) — observed from the result, so a caller can
         #: check where the jax plane actually executed
         self.sweep_device = None
+        #: the jitted sweep's rank tables for the last template swept,
+        #: on the device (see ``_sweep_jax``)
+        self._sweep_tables = None
         #: optional per-invocation runtime multipliers keyed by
         #: ``(tenant identity, function name)`` — the placement layer's
         #: co-location/noisy-neighbour model (see
@@ -1338,7 +1386,7 @@ class FleetEngine:
             collapses to a candidate-vectorized longest-path sweep over
             the shared event skeleton (no heap, no per-event Python;
             ``plane_backend="jax"`` runs the sweep as a jitted
-            ``lax.scan``),
+            program, one step per topological rank),
           * **constrained** — finite capacity, cold starts, or
             ``collect_carry``: cells replay the exact scalar event loop
             *table-driven* off the precomputed runtime/cost planes —
@@ -1391,8 +1439,10 @@ class FleetEngine:
                                              times_list, carry,
                                              collect_carry, call)
 
-            nodes, names, cpu, mem = self._candidate_arrays(template,
-                                                            config_sets)
+            with telemetry.span("fleet.candidates", call=call,
+                                cells=len(config_sets) * len(template)):
+                nodes, names, cpu, mem = self._candidate_arrays(
+                    template, config_sets)
             if any(len(t) for t in times_list):
                 self._check_candidates_placeable(template, config_sets,
                                                  cpu, mem)
@@ -1547,15 +1597,15 @@ class FleetEngine:
         ``apply_configs`` hand the scalar path."""
         nodes = list(template.nodes.values())
         names = [n.name for n in nodes]
-        n_cand, n_nodes = len(config_sets), len(nodes)
-        cpu = np.empty((n_cand, n_nodes))
-        mem = np.empty((n_cand, n_nodes))
-        for ci, configs in enumerate(config_sets):
-            for vi, node in enumerate(nodes):
-                cfg = configs.get(node.name, node.config).copy()
-                cpu[ci, vi] = cfg.cpu
-                mem[ci, vi] = cfg.mem
-        return nodes, names, cpu, mem
+        shape = (len(config_sets), len(nodes))
+        cfgs = [configs.get(node.name, node.config)
+                for configs in config_sets for node in nodes]
+        cpu = np.array([c.cpu for c in cfgs], dtype=np.float64)
+        mem = np.array([c.mem for c in cfgs], dtype=np.float64)
+        return (nodes, names,
+                _quantized(cpu, CPU_MIN, CPU_MAX, CPU_STEP).reshape(shape),
+                _quantized(mem, MEM_MIN_MB, MEM_MAX_MB,
+                           MEM_STEP_MB).reshape(shape))
 
     def _check_candidates_placeable(self, template, config_sets,
                                     cpu, mem) -> None:
@@ -2150,32 +2200,44 @@ class FleetEngine:
 
     def _sweep_jax(self, template, order, col, t_all, rt,
                    call) -> np.ndarray:
-        """The fast plane's longest-path sweep as a jitted ``lax.scan``
-        over topological ranks (x64): all C×N×V finish times advance as
-        one device program — the fleet-step end state this repo aims
-        at. Same recurrence as the numpy sweep, bit-identical where
-        float64 is native (the CPU; validated by tests). A TPU emulates
-        float64: on a TPU v5e the results differ from numpy by up to
-        6.4e-14 relative (``chip_smoke.py`` holds them to 1e-12).
-        Requires jax."""
+        """The fast plane's longest-path sweep as one jitted device
+        program (x64), one step per topological rank: all C×N×V finish
+        times advance in as many steps as the workflow is deep, and each
+        rank gathers only its own predecessors. Counts the steps, the
+        gathered slots (padding included) and the live edges of each
+        sweep (``fleet.sweep.steps``, ``.slots``, ``.edges``). Same
+        recurrence as the numpy sweep, bit-identical where float64 is
+        native (the CPU; validated by tests). A TPU emulates float64: on
+        a TPU v5e the results differ from numpy by up to 6.4e-14
+        relative (``chip_smoke.py`` holds them to 1e-12). Requires
+        jax."""
         import jax
         sweep = _jax_sweep_fn()
-        order_idx = np.array([col[name] for name in order], dtype=np.int32)
-        max_p = max((len(template.predecessors(n)) for n in order),
-                    default=1)
-        max_p = max(max_p, 1)
-        pred_idx = np.zeros((len(order), max_p), dtype=np.int32)
-        pred_mask = np.zeros((len(order), max_p), dtype=bool)
-        for k, name in enumerate(order):
-            for j, p in enumerate(template.predecessors(name)):
-                pred_idx[k, j] = col[p]
-                pred_mask[k, j] = True
-        shape = (rt.shape[0], t_all.shape[0], rt.shape[1], max_p)
+        # the tables hold while the template's structure does: every
+        # structural edit drops its cached topological order
+        kept = self._sweep_tables
+        if kept is None or kept["template"] is not template \
+                or kept["topo"] is not template._topo:
+            ranked, preds, masks = _rank_tables(template, order)
+            kept = {"template": template, "topo": template._topo,
+                    "cols": np.array([col[name] for name in ranked]),
+                    "slots": sum(p.size for p in preds),
+                    "edges": sum(int(m.sum()) for m in masks)}
+            with jax.enable_x64(True):
+                kept["preds"], kept["masks"] = jax.device_put((preds,
+                                                               masks))
+            self._sweep_tables = kept
+        telemetry.count("fleet.sweep.steps", len(kept["preds"]))
+        telemetry.count("fleet.sweep.slots", kept["slots"])
+        telemetry.count("fleet.sweep.edges", kept["edges"])
+        shape = (rt.shape[0], t_all.shape[0],
+                 tuple(p.shape for p in kept["preds"]))
         if shape not in _SWEEP_SHAPES:
             _SWEEP_SHAPES.add(shape)
             telemetry.count("fleet.sweep.shapes")
         with jax.enable_x64(True):
-            fin = sweep(t_all, rt, order_idx, pred_idx, pred_mask)
+            fin = sweep(t_all, rt[:, kept["cols"]], kept["preds"],
+                        kept["masks"])
             self.sweep_device = fin.device
             with telemetry.span("fleet.fetch", call=call):
                 return np.asarray(fin)

@@ -641,7 +641,7 @@ def test_pricing_vectorization_redetects_after_swap_and_mutation():
     assert report.total_cost == 0.0
 
 
-# -- the jitted lax.scan fleet step ------------------------------------
+# -- the jitted fleet step ----------------------------------------------
 
 def test_jax_plane_backend_matches_numpy_bitwise():
     pytest.importorskip("jax")
@@ -658,6 +658,28 @@ def test_jax_plane_backend_matches_numpy_bitwise():
     # the sweep really ran as a jax program, on JAX's default device
     import jax
     assert jax_engine.sweep_device.platform == jax.default_backend()
+
+
+def test_jax_sweep_follows_a_structural_edit_of_the_template():
+    """The jitted sweep keeps its rank tables between calls; an edge
+    added to the template deepens it, and the next call sweeps the new
+    structure, still bit-identical to the numpy plane."""
+    pytest.importorskip("jax")
+    template = TOPOLOGIES["fan"]()
+    cands = candidate_sets(template, 2, seed=19)
+    seeds = arrival_sets(1)
+    jax_engine = make_engine(plane_backend="jax")
+    before = jax_engine.run_many(template, cands, seeds)
+    # one branch of the fan now waits for another
+    source, = [n for n in template.nodes if not template.predecessors(n)]
+    first, second = template.successors(source)[:2]
+    template.add_edge(first, second)
+    jax_reports = jax_engine.run_many(template, cands, seeds)
+    assert any(a.finishes.tobytes() != b.finishes.tobytes()
+               for a, b in zip(jax_reports, before))
+    for got, want in zip(jax_reports,
+                         make_engine().run_many(template, cands, seeds)):
+        assert_reports_identical(got, want)
 
 
 #: values whose running sums round half to even, reach the subnormals,
@@ -694,6 +716,35 @@ def test_busy_ledger_fold_matches_the_python_loop_bit_for_bit(branch, m):
     got = _fold_rows(rows)
     assert all(type(x) is float for x in got)
     assert [x.hex() for x in got] == [x.hex() for x in want]
+
+
+def test_candidate_arrays_quantize_as_each_config_copy_does():
+    """The (C, V) config arrays against ``ResourceConfig.copy`` per
+    cell, bit for bit: configs moved off the lattice after they were
+    made, exact halves of a step (rounded to even), values beyond
+    either end, and functions a candidate leaves at the template's."""
+    template = TOPOLOGIES["layered"]()
+    names = list(template.nodes)
+    rng = np.random.default_rng(23)
+    config_sets = []
+    for c in range(6):
+        configs = {}
+        for name in names[c % 2::2]:
+            cfg = ResourceConfig(cpu=1.0, mem=1024.0)
+            k = rng.integers(-3, 110)
+            cfg.cpu = float(rng.choice([(k + 0.5) * 0.1, k * 0.1 + 0.03,
+                                        rng.uniform(-1.0, 12.0)]))
+            cfg.mem = float(rng.choice([(k + 0.5) * 64.0,
+                                        rng.uniform(0.0, 11000.0)]))
+            configs[name] = cfg
+        config_sets.append(configs)
+    engine = make_engine()
+    nodes, _, cpu, mem = engine._candidate_arrays(template, config_sets)
+    for ci, configs in enumerate(config_sets):
+        for vi, node in enumerate(nodes):
+            want = configs.get(node.name, node.config).copy()
+            assert cpu[ci, vi].hex() == float(want.cpu).hex()
+            assert mem[ci, vi].hex() == float(want.mem).hex()
 
 
 def test_jax_plane_names_numpy_sweep_under_replay_noise():

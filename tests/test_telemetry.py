@@ -23,7 +23,7 @@ from repro.core.cost import PricingModel
 from repro.core.engine import (ClusterModel, ColdStartModel, FleetEngine,
                                PoissonArrivals)
 from repro.core.resources import ResourceConfig
-from repro.serverless.generator import layered_workflow
+from repro.serverless.generator import fan_workflow, layered_workflow
 from repro.serverless.platform import SimulatedPlatform, StochasticBackend
 
 CONSTRAINED_KW = dict(cluster=ClusterModel(total_cpu=12.0,
@@ -129,6 +129,8 @@ def test_each_run_many_is_one_span_with_its_phases_inside(plane):
                 and s[3]["call"] == root[3]["call"]]
         assert kids and all(_inside(k, root) for k in kids)
         names = [k[2] for k in kids]
+        made, = [k for k in kids if k[2] == "fleet.candidates"]
+        assert made[3]["cells"] == 3 * 8
         assert names.count("fleet.surface") == 1
         assert names.count("fleet.price") == 1
         if plane == "fast":
@@ -174,6 +176,16 @@ def test_counters_name_the_plane_batch_eligibility_reports(plane):
         # start-ordered ones with it
         fold = "ordered" if plane == "stochastic" else "repeat"
         assert delta.pop(f"fleet.ledger.rows.{fold}") == cells * 8
+        if plane == "fast":
+            # the jitted sweep (the stochastic plane sweeps in numpy):
+            # one step per rank, each gathering its own predecessors
+            ranks = _ranks(template)
+            preds = [len(template.predecessors(n)) for n in template.nodes]
+            assert delta.pop("fleet.sweep.steps") == len(ranks)
+            assert delta.pop("fleet.sweep.slots") == sum(
+                len(r) * max(len(template.predecessors(n)) for n in r)
+                for r in ranks)
+            assert delta.pop("fleet.sweep.edges") == sum(preds)
     else:
         assert delta.pop("fleet.cells.per_cell") == cells
     # the sweep's shape may have been seen by an earlier test here
@@ -189,6 +201,35 @@ def test_a_new_sweep_shape_is_counted_once():
     engine.run_many(template, cands, seeds)
     engine.run_many(template, cands, seeds)
     assert telemetry.counters()["fleet.sweep.shapes"] == before + 1
+
+
+def _ranks(template):
+    """The functions grouped by their longest hop count from a
+    source."""
+    rank = {}
+    for name in template.topological_order():
+        rank[name] = 1 + max((rank[p] for p in template.predecessors(name)),
+                             default=-1)
+    return [[n for n in rank if rank[n] == r]
+            for r in range(max(rank.values()) + 1)]
+
+
+def test_sweep_counters_count_steps_padded_slots_and_edges():
+    """A fan-in, three ranks deep: the source gathers nothing, each of
+    the 5 branches gathers its one predecessor, and the join gathers
+    all 5 — 10 slots, every one an edge."""
+    template = fan_workflow(5, seed=2)
+    _, _, seeds = _inputs()
+    engine = _engine("fast")
+    for _ in range(2):
+        before = telemetry.counters()
+        engine.run_many(template, [{}, {}], seeds)
+        after = telemetry.counters()
+        assert {k: after[k] - before.get(k, 0) for k in (
+            "fleet.sweep.steps", "fleet.sweep.slots",
+            "fleet.sweep.edges")} == {"fleet.sweep.steps": 3,
+                                      "fleet.sweep.slots": 5 + 5,
+                                      "fleet.sweep.edges": 10}
 
 
 @pytest.mark.parametrize("plane", ["fast", "constrained"])

@@ -69,18 +69,39 @@ def test_ssd_scan_compiles_at_zamba2_widths(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _sweep_specs(sharding, c, i, ranks):
+    """The jitted sweep's arguments for ``ranks``, a (width, in-degree)
+    per topological rank."""
+    v = sum(w for w, _ in ranks)
+    return (_spec(sharding, (i,), jnp.float64),
+            _spec(sharding, (c, v), jnp.float64),
+            tuple(_spec(sharding, r, jnp.int32) for r in ranks),
+            tuple(_spec(sharding, r, jnp.bool_) for r in ranks))
+
+
 def test_fleet_sweep_compiles_in_float64(one_chip):
-    """The jax replay plane's lax.scan sweep at C=64 candidates,
-    I=4096 instances, V=64 functions (a 62-wide fan-out's join)."""
+    """The jax replay plane's rank sweep at C=64 candidates, I=4096
+    instances, V=64 functions (a 62-wide fan-out's join)."""
     from repro.core.engine import _jax_sweep_fn
-    c, i, v, p = 64, 4096, 64, 62
+    c, i = 64, 4096
     with jax.enable_x64(True):
-        compiled = _jax_sweep_fn().lower(
-            _spec(one_chip, (i,), jnp.float64),
-            _spec(one_chip, (c, v), jnp.float64),
-            _spec(one_chip, (v,), jnp.int32),
-            _spec(one_chip, (v, p), jnp.int32),
-            _spec(one_chip, (v, p), jnp.bool_)).compile()
+        compiled = _jax_sweep_fn().lower(*_sweep_specs(
+            one_chip, c, i, [(1, 0), (62, 1), (1, 62)])).compile()
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes == c * i * 8
     assert mem.temp_size_in_bytes < 16e9          # one v5e chip's HBM
+
+
+def test_fleet_sweep_compiles_at_1000genome_width(one_chip):
+    """The rank sweep of the 1000Genome workflow (22 chromosomes, 10
+    shards each: 242 sources, 22 merges of 10 shards, 308 tasks after
+    the merge and sifting) at C=8, I=4096: the (C, I, V) finish tensor
+    is the program's largest temporary."""
+    from repro.core.engine import _jax_sweep_fn
+    c, i, v = 8, 4096, 572
+    with jax.enable_x64(True):
+        compiled = _jax_sweep_fn().lower(*_sweep_specs(
+            one_chip, c, i, [(242, 0), (22, 10), (308, 2)])).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == c * i * 8
+    assert c * i * v * 8 <= mem.temp_size_in_bytes < 16e9
