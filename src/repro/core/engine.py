@@ -985,9 +985,31 @@ def _fold_rows(rows: np.ndarray) -> List[float]:
     floats: the scalar event loop's ``acc += x`` over the same values in
     the same order, bit for bit. ``np.add.accumulate`` adds strictly in
     sequence; ``np.sum`` (pairwise), ``math.fsum`` (exact) and ``m * x``
-    each round differently."""
+    each round differently. The noise-on ledger folds its rows here; the
+    noise-off ledger, whose row repeats one value, folds each distinct
+    value once per call instead (:func:`_fold_repeats`)."""
     # + 0.0: the loop starts from +0.0, so a row of -0.0 sums to +0.0
     return (np.add.accumulate(rows, axis=1)[:, -1] + 0.0).tolist()
+
+
+def _fold_repeats(values: np.ndarray, sizes: Sequence[int],
+                  block: int) -> np.ndarray:
+    """``_fold_rows`` of every row that repeats one of ``values`` m times,
+    for each m in ``sizes`` (ascending), bit for bit: out[u, j] is
+    values[u] summed left to right sizes[j] times from 0.0. Such a sum
+    depends on the value and m alone, and m copies are the prefix of
+    sizes[-1] copies, so each value is folded once, to the largest size,
+    and read at every size. Folds ``block`` values at a time, so the
+    temporary never exceeds ``(block, sizes[-1])``."""
+    cols = np.asarray(sizes) - 1
+    out = np.empty((values.size, cols.size))
+    for lo in range(0, values.size, block):
+        part = values[lo:lo + block]
+        out[lo:lo + part.size] = np.add.accumulate(
+            np.broadcast_to(part[:, None], (part.size, sizes[-1])),
+            axis=1)[:, cols]
+    # + 0.0: as in _fold_rows, a sum of -0.0 starts from +0.0
+    return out + 0.0
 
 
 class _PlannedBackend(BaseBackend):
@@ -2129,6 +2151,27 @@ class FleetEngine:
             fn_keys = [f"{template.identity}/{name}" for name in names]
             pfq = dict.fromkeys(fn_keys, 0.0)
             busy = carry.busy if carry is not None else []
+            # per-fn busy ledger: the scalar loop's left-to-right
+            # accumulation in admission (= start-event) order, one row
+            # per function of each swept cell. With noise off every
+            # instance contributes the same value, so a row repeats it,
+            # any admission order gives the same bits, and the sum
+            # depends on the runtime and the fleet size alone: each
+            # distinct runtime of the call (by its bits, so -0.0, +0.0
+            # and NaN payloads stay apart) is folded once, V at a time,
+            # and each cell reads its sums. With noise on, each cell's
+            # instances are summed in start-time order (stable on ties).
+            sizes = sorted({m for m in counts if m > 1})
+            if noise is None and sizes:
+                bits, rt_row = np.unique(rt.view(np.int64),
+                                         return_inverse=True)
+                rt_row = rt_row.reshape(rt.shape)
+                size_col = {m: j for j, m in enumerate(sizes)}
+                with telemetry.span("fleet.ledger.fold", call=call,
+                                    distinct=int(bits.size)):
+                    folded = _fold_repeats(bits.view(np.float64), sizes,
+                                           len(names))
+                telemetry.count("fleet.ledger.rows.folded", int(bits.size))
             for si, times in enumerate(times_list):
                 m = counts[si]
                 seg = slice(offsets[si], offsets[si] + m)
@@ -2157,18 +2200,10 @@ class FleetEngine:
                     for f, _, _ in busy:
                         if f > t0 and f > t_last:
                             t_last = float(f)
-                    # per-fn busy ledger: the scalar loop's left-to-right
-                    # accumulation in admission (= start-event) order, one
-                    # row per function. With noise off every instance
-                    # contributes the same value, so the row repeats it and
-                    # any admission order gives the same bits; with noise
-                    # on, instances are summed in start-time order (stable
-                    # on ties).
                     with telemetry.span("fleet.ledger", call=call,
                                         cand=int(ci)):
                         if noise is None:
-                            rows = np.broadcast_to(rt[k][:, None],
-                                                   (len(names), m))
+                            sums = folded[rt_row[k], size_col[m]].tolist()
                             telemetry.count("fleet.ledger.rows.repeat",
                                             len(names))
                         else:
@@ -2177,9 +2212,10 @@ class FleetEngine:
                                 starts = start_by_node[name][k, seg]
                                 rows[v] = rt_eff[k, seg, v][
                                     np.argsort(starts, kind="stable")]
+                            sums = _fold_rows(rows)
                             telemetry.count("fleet.ledger.rows.ordered",
                                             len(names))
-                        fn_busy = dict(zip(fn_keys, _fold_rows(rows)))
+                        fn_busy = dict(zip(fn_keys, sums))
                     telemetry.count("fleet.cells.swept")
                     zeros = np.zeros(m)
                     cost = (np.full(m, cand_cost[k]) if noise is None

@@ -16,11 +16,14 @@ import math
 import numpy as np
 import pytest
 
+from repro.core import engine as engine_module
+from repro.core import telemetry
 from repro.core.backend import CallableBackend
 from repro.core.cost import PricingModel
 from repro.core.engine import (ClusterModel, ColdStartModel, FleetCarry,
-                               FleetEngine, PoissonArrivals, _fold_rows)
-from repro.core.resources import ResourceConfig
+                               FleetEngine, PoissonArrivals, _fold_repeats,
+                               _fold_rows)
+from repro.core.resources import CPU_STEP, ResourceConfig
 from repro.serverless.generator import (chain_workflow, diamond_workflow,
                                         fan_workflow, layered_workflow)
 from repro.serverless.platform import (AnalyticBackend, SimulatedPlatform,
@@ -716,6 +719,143 @@ def test_busy_ledger_fold_matches_the_python_loop_bit_for_bit(branch, m):
     got = _fold_rows(rows)
     assert all(type(x) is float for x in got)
     assert [x.hex() for x in got] == [x.hex() for x in want]
+
+
+def _loop_sum(x: float, m: int) -> float:
+    acc = 0.0
+    for _ in range(m):
+        acc += x
+    return acc
+
+
+@pytest.mark.parametrize("block", [1, 2, 7])
+def test_distinct_runtime_fold_matches_the_python_loop_bit_for_bit(block):
+    """Each distinct value folded once and read at every fleet size,
+    against ``acc += x`` and ``_fold_rows`` of the repeated row, in
+    blocks that split the values unevenly."""
+    values = np.array(FOLD_VALUES + [0.0, math.inf, 1.7e308, 2.5])
+    sizes = [2, 3, 4096]
+    with np.errstate(over="ignore"):         # 1.7e308 reaches inf
+        got = _fold_repeats(values, sizes, block)
+        assert got.shape == (values.size, len(sizes))
+        for j, m in enumerate(sizes):
+            rows = np.broadcast_to(values[:, None], (values.size, m))
+            want = [_loop_sum(float(x), m) for x in values]
+            assert [x.hex() for x in got[:, j].tolist()] \
+                == [x.hex() for x in want] \
+                == [x.hex() for x in _fold_rows(rows)]
+
+
+class _TableBackend(AnalyticBackend):
+    """A deterministic surface whose runtime is ``table[k]`` for a
+    function given k lattice steps of vCPU: a test chooses each cell's
+    runtimes, shared or distinct, down to the bit."""
+
+    def __init__(self, table):
+        super().__init__()
+        self.table = np.asarray(table, dtype=np.float64)
+
+    def _surface(self, cpu, mem, spec_arrays):
+        k = np.rint(np.asarray(cpu) / CPU_STEP).astype(int)
+        return self.table[k], np.zeros(k.shape, dtype=bool)
+
+
+class _AccumulateSpy:
+    """numpy, recording the shape of every ``np.add.accumulate``."""
+
+    def __init__(self):
+        shapes = self.shapes = []
+
+        class _Add:
+            def __getattr__(self, name):
+                return getattr(np.add, name)
+
+            def __call__(self, *args, **kw):
+                return np.add(*args, **kw)
+
+            def accumulate(self, array, *args, **kw):
+                shapes.append(np.shape(array))
+                return np.add.accumulate(array, *args, **kw)
+
+        self.add = _Add()
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+
+#: runtime table rows by case: index k is the runtime at k vCPU steps
+LEDGER_TABLES = {
+    # four values shared across candidates and functions
+    "shared": [0.1, 3.0000000000000004, 7.25, 0.1 + 0.2],
+    # signed zeros, the least subnormal, a huge value
+    "edge_values": [-0.0, 0.0, 2.0 ** -1074, 1e300, 0.1, 3.0],
+    # every (candidate, function) its own runtime: C blocks of V
+    "all_distinct": [1.0 + 0.37 * k for k in range(20)],
+}
+
+
+@pytest.mark.parametrize("case", list(LEDGER_TABLES))
+def test_noise_off_ledger_folds_each_distinct_runtime_once(case,
+                                                           monkeypatch):
+    """The noise-off busy ledger of one call with arrival sets of
+    different sizes: each function's busy time is its runtime added m
+    times from 0.0 (``acc += x`` and ``_fold_rows`` of the repeated row),
+    bit for bit; the cells of up to 3 instances equal the scalar loop
+    outright; each distinct runtime is folded once, with no temporary
+    beyond one cell's (V, max m) row block."""
+    template = TOPOLOGIES["chain"]()
+    names = list(template.nodes)
+    n_cand, n_fn = 4, len(names)
+    table = LEDGER_TABLES[case]
+    rng = np.random.default_rng(31)
+    if case == "all_distinct":
+        picks = np.arange(n_cand * n_fn).reshape(n_cand, n_fn)
+    else:
+        picks = rng.integers(0, len(table), size=(n_cand, n_fn))
+    # lattice steps 1..len(table): k vCPU steps give table[k - 1]
+    backend = _TableBackend([math.nan] + list(table))
+    cands = [{name: ResourceConfig(cpu=float(picks[c, v] + 1) * CPU_STEP,
+                                   mem=2048.0)
+              for v, name in enumerate(names)} for c in range(n_cand)]
+    sizes = [3, 4096, 2]
+    seeds = [PoissonArrivals(0.25, m, seed=s).times()
+             for s, m in enumerate(sizes)]
+    engine = FleetEngine(backend, pricing=SimulatedPlatform().pricing)
+    spy = _AccumulateSpy()
+    monkeypatch.setattr(engine_module, "np", spy)
+    before = telemetry.counters()
+    reports = engine.run_many(template, cands, seeds)
+    after = telemetry.counters()
+    monkeypatch.undo()
+    delta = {k: v - before.get(k, 0) for k, v in after.items()}
+    runtime = np.array(table)[picks]
+    distinct = len({x.hex() for x in runtime.ravel().tolist()})
+    if case == "all_distinct":
+        assert distinct == n_cand * n_fn
+    assert delta["fleet.ledger.rows.folded"] == distinct
+    assert delta["fleet.ledger.rows.repeat"] == \
+        n_cand * len(sizes) * n_fn
+    # the fold's blocks: ceil(distinct / V), none beyond (V, max m)
+    assert sum(shape[1:] == (max(sizes),) for shape in spy.shapes) \
+        == -(-distinct // n_fn)
+    assert all(shape[0] <= n_fn and shape[1] <= max(sizes)
+               for shape in spy.shapes)
+    k = 0
+    for c, configs in enumerate(cands):
+        for times, m in zip(seeds, sizes):
+            keys = [f"{template.identity}/{name}" for name in names]
+            rows = np.broadcast_to(runtime[c][:, None], (n_fn, m))
+            got = {key: x.hex()
+                   for key, x in reports[k].busy_by_function.items()}
+            assert got == {key: _loop_sum(float(x), m).hex()
+                           for key, x in zip(keys, runtime[c])}
+            assert got == {key: x.hex()
+                           for key, x in zip(keys, _fold_rows(rows))}
+            if m <= 3:
+                assert_reports_identical(
+                    reports[k], scalar_cell(engine, template, configs,
+                                            times))
+            k += 1
 
 
 def test_candidate_arrays_quantize_as_each_config_copy_does():

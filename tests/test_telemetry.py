@@ -70,6 +70,20 @@ def _inputs(n_cand=3, n_seeds=2, n=6):
     return template, cands, seeds
 
 
+def _distinct_runtimes(plane, template, cands):
+    """The distinct runtimes (by bits) of every candidate's functions,
+    one scalar ``invoke_batch`` per candidate: what the noise-off ledger
+    folds once per call."""
+    backend = _engine(plane).backend
+    seen = set()
+    for configs in cands:
+        wf = template.copy()
+        wf.apply_configs(configs)
+        runtimes, _ = backend.invoke_batch(list(wf))
+        seen.update(float(x).hex() for x in runtimes)
+    return len(seen)
+
+
 def _traced(fn):
     """Run ``fn`` under a profiler trace; returns its result and the
     trace's ``fleet.*`` spans as (start, end, name, stats)."""
@@ -138,14 +152,22 @@ def test_each_run_many_is_one_span_with_its_phases_inside(plane):
             assert names.count("fleet.fetch") == 1
             assert names.count("fleet.assemble") == 1
             assert names.count("fleet.ledger") == 3 * 2
+            # the noise-off ledger folds each distinct runtime once per
+            # call, before the cells read their sums
+            assert names.count("fleet.ledger.fold") == 1
             sweep = next(k for k in kids if k[2] == "fleet.sweep")
             assert sweep[3]["cells"] == 3 * 2
             assemble = next(k for k in kids if k[2] == "fleet.assemble")
+            fold = next(k for k in kids if k[2] == "fleet.ledger.fold")
+            assert _inside(fold, assemble)
+            assert fold[3]["distinct"] == _distinct_runtimes(plane,
+                                                             template, cands)
             for k in kids:
                 if k[2] == "fleet.fetch":
                     assert _inside(k, sweep)
                 if k[2] == "fleet.ledger":
                     assert _inside(k, assemble)
+                    assert fold[1] <= k[0]
             assert sorted(k[3]["cand"] for k in kids
                           if k[2] == "fleet.ledger") == [0, 0, 1, 1, 2, 2]
         else:
@@ -176,6 +198,10 @@ def test_counters_name_the_plane_batch_eligibility_reports(plane):
         # start-ordered ones with it
         fold = "ordered" if plane == "stochastic" else "repeat"
         assert delta.pop(f"fleet.ledger.rows.{fold}") == cells * 8
+        if fold == "repeat":
+            # served from one fold per distinct runtime of the call
+            assert delta.pop("fleet.ledger.rows.folded") == \
+                _distinct_runtimes(plane, template, cands)
         if plane == "fast":
             # the jitted sweep (the stochastic plane sweeps in numpy):
             # one step per rank, each gathering its own predecessors
