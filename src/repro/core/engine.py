@@ -23,15 +23,15 @@ instances against a cluster model:
   * **batched replays** — :meth:`FleetEngine.run_many` replays C
     candidate config-maps × S arrival seeds over a shared topology as
     one vectorized evaluation: ONE ``invoke_config_batch``
-    response-surface call and ONE ``cost_batch`` pricing expression for
-    the whole plane, then either a candidate-vectorized longest-path
-    sweep (contention-free fleets; optionally a jitted sweep by
-    topological rank via ``plane_backend="jax"``) or table-driven replays of the exact
-    event loop (finite capacity, cold starts, carry collection) —
-    bit-identical to the looped scalar path either way. Stochastic
-    backends join the plane through a paired replay-noise stream; only
-    non-``batch_safe`` backends and empty templates still take the
-    serial fallback,
+    response-surface call and ONE priced cost table for the whole
+    plane, then either a candidate-vectorized longest-path sweep (the
+    fast plane: contention-free fleets; optionally jitted by
+    topological rank via ``plane_backend="jax"``) or table-driven
+    replays of the exact event loop (the constrained plane: finite
+    capacity, cold starts, carry collection) — bit-identical to the
+    looped scalar path either way. Stochastic backends join them
+    through a paired replay-noise stream; only non-``batch_safe``
+    backends and empty templates take the serial plane,
   * **epoch resumption** — a run can start from a :class:`FleetCarry`
     (warm containers plus still-running invocations from a previous
     bounded epoch) and emit the carry for the next epoch, so an online
@@ -65,7 +65,7 @@ from typing import (Callable, Dict, List, Mapping, Optional, Sequence,
 import numpy as np
 
 from repro.core import telemetry
-from repro.core.backend import BaseBackend, RuntimeBackend, as_backend
+from repro.core.backend import RuntimeBackend, as_backend
 from repro.core.cost import DEFAULT_PRICING, PricingModel
 from repro.core.dag import Workflow
 from repro.core.resources import (CPU_MAX, CPU_MIN, CPU_STEP, MEM_MAX_MB,
@@ -1012,28 +1012,6 @@ def _fold_repeats(values: np.ndarray, sizes: Sequence[int],
     return out + 0.0
 
 
-class _PlannedBackend(BaseBackend):
-    """Replays a precomputed ``(runtime, failed)`` plan keyed by node
-    identity. The planned/per-cell replay paths use it to drive the
-    exact scalar event loop off ONE response-surface call: every
-    invocation looks its outcome up in the plan instead of dispatching
-    into the real backend again."""
-
-    deterministic = True
-
-    def __init__(self, plan: Dict[int, Tuple[float, bool]]):
-        self._plan = plan
-
-    def invoke_batch(self, nodes: Sequence) -> Tuple[np.ndarray, np.ndarray]:
-        runtimes = np.empty(len(nodes), dtype=np.float64)
-        failed = np.zeros(len(nodes), dtype=bool)
-        for i, node in enumerate(nodes):
-            rt, bad = self._plan[id(node)]
-            runtimes[i] = rt
-            failed[i] = bad
-        return runtimes, failed
-
-
 #: lazily-built jitted sweep — see _jax_sweep_fn
 _JAX_SWEEP = None
 #: (C', N, ((width, in-degree) per rank)) signatures the jitted sweep
@@ -1148,7 +1126,7 @@ class FleetEngine:
         #: seeded fault-injection plane (a
         #: :class:`repro.core.faults.FaultModel`); ``None`` disables
         #: fault injection entirely — the engine is then bit-identical
-        #: to its pre-fault behaviour on all four replay planes
+        #: to its pre-fault behaviour on all three replay planes
         self.faults = faults
         #: per-function recovery policies (a
         #: :class:`repro.core.faults.ResilienceModel`): retry with
@@ -1156,11 +1134,6 @@ class FleetEngine:
         #: request hedging. Inert without ``faults`` — there is nothing
         #: to recover from, so ``resilience`` alone changes no bits
         self.resilience = resilience
-        #: planned-cell hook: ``(FaultStream, row offset)`` installed
-        #: by a parent ``run_many`` so a shadow engine's cells draw
-        #: from the parent plane's ONE fault stream instead of
-        #: re-drawing per cell (the paired fault-stream contract)
-        self._fault_stream: Optional[Tuple[object, int]] = None
         if plane_backend not in ("numpy", "jax"):
             raise ValueError(
                 f"plane_backend must be 'numpy' or 'jax', got "
@@ -1233,13 +1206,9 @@ class FleetEngine:
                        else FleetCarry())
             return self._empty_report(carry_out=out)
 
-        if (carry is None and not collect_carry
-                and len(workflows) == 1 and not self.cluster.finite
-                and self.cold_start.delay_s == 0.0
-                and self.scale is None and self.faults is None):
-            # degenerate case (every Environment.execute sample): no
-            # contention => runtimes are schedule-independent, so skip
-            # the event machinery — ONE batch call + longest path
+        if self._takes_degenerate(len(workflows), carry, collect_carry):
+            # degenerate case (every Environment.execute sample): ONE
+            # batch call + longest path, no event machinery
             return self._run_degenerate(workflows[0], float(times[0]))
 
         state = _FleetState(workflows, times)
@@ -1248,21 +1217,18 @@ class FleetEngine:
         if self.faults is not None:
             # function columns in first-seen (wf order, node insertion)
             # order — the exact indexing run_many's candidate arrays
-            # use for a homogeneous fleet, so a planned shadow cell and
-            # the table loop read the same stream coordinates
+            # use for a homogeneous fleet, so the table loop reads the
+            # same stream coordinates
             cols: Dict[tuple, int] = {}
             for wf in workflows:
                 for name in wf.nodes:
                     key = (wf.identity, name)
                     if key not in cols:
                         cols[key] = len(cols)
-            if self._fault_stream is not None:
-                stream, f_offset = self._fault_stream
-            else:
-                stream = self.faults.fault_stream(len(workflows), len(cols))
-                f_offset = 0
             fctx = _FaultCtx(self.faults, self.resilience, self.pricing,
-                             stream, f_offset, cols)
+                             self.faults.fault_stream(len(workflows),
+                                                      len(cols)),
+                             0, cols)
 
         seq = itertools.count()
         events: List[Tuple[float, int, int, int, object]] = [
@@ -1399,25 +1365,25 @@ class FleetEngine:
 
         Any ``batch_safe`` backend exposing ``invoke_config_batch``
         evaluates the whole C×V response surface in ONE call and prices
-        it in ONE ``cost_batch`` expression; the plane the cells then
-        replay through depends on what actually binds
-        (:meth:`batch_eligibility` reports the routing):
+        it into ONE cost table (a ``cost_batch`` expression, or scalar
+        ``function_cost`` per entry for a pricing model that does not
+        vectorize); the plane the cells then replay through depends on
+        what actually binds (:meth:`batch_eligibility` reports the
+        routing):
 
           * **fast** — infinite cluster, cold starts off, no carried
             backlog to re-enact: instances never interact, so the plane
             collapses to a candidate-vectorized longest-path sweep over
             the shared event skeleton (no heap, no per-event Python;
             ``plane_backend="jax"`` runs the sweep as a jitted
-            program, one step per topological rank),
-          * **constrained** — finite capacity, cold starts, or
-            ``collect_carry``: cells replay the exact scalar event loop
-            *table-driven* off the precomputed runtime/cost planes —
-            zero backend or pricing calls, zero template copies inside
-            the loops,
-          * **planned** — the pricing model does not vectorize: cells
-            replay through per-instance workflow copies against the
-            precomputed runtime plan so custom scalar pricing sees real
-            node objects,
+            program, one step per topological rank); cells it cannot
+            sweep (unbounded failures, fleets of one) replay on their
+            own by the route ``run`` takes,
+          * **constrained** — finite capacity, cold starts, replica
+            pools, faults or ``collect_carry``: cells replay the exact
+            scalar event loop *table-driven* off the precomputed
+            runtime/cost tables — zero backend or pricing calls, zero
+            template copies inside the loops,
           * **serial** — an empty template or a backend that is not
             ``batch_safe`` (opaque/stateful with no replay-stream
             contract) genuinely serializes: the exact looped-``run``
@@ -1495,12 +1461,6 @@ class FleetEngine:
                 fstream = self.faults.fault_stream(
                     sum(len(t) for t in times_list), len(nodes))
 
-            if plane == "planned":
-                return self._run_many_planned(template, config_sets,
-                                              times_list, carry,
-                                              collect_carry, names,
-                                              runtimes, failed, noise,
-                                              fstream, call)
             if plane == "constrained":
                 return self._run_many_constrained(template, config_sets,
                                                   times_list, carry,
@@ -1541,10 +1501,6 @@ class FleetEngine:
                 "config_surface/replay_noise replay-stream contract")
         if reasons:
             return {"plane": "serial", "reasons": reasons}
-        if not self._pricing_vectorized:
-            return {"plane": "planned", "reasons": [
-                "pricing model does not vectorize (scalar overrides "
-                "without a matching cost_batch)"]}
         constrained = []
         if self.cluster.finite:
             constrained.append("finite cluster capacity")
@@ -1575,18 +1531,20 @@ class FleetEngine:
         """Why would (or wouldn't) :meth:`run_many` vectorize this
         replay? Returns::
 
-            {"plane": "fast" | "constrained" | "planned" | "serial",
+            {"plane": "fast" | "constrained" | "serial",
              "vectorized": bool,   # fast/constrained plane
              "reasons": [...],     # what routed it off the fast plane
              "serial_candidates": None | [candidate indices]}
 
         ``reasons`` names the binding constraints (finite cluster, cold
-        starts, carry collection, backend gate, pricing model). With
+        starts, carry collection, backend gate). The pricing model
+        never routes: one that does not vectorize only fills the cost
+        table entry by entry. With
         ``probe_candidates=True`` the response surface is evaluated
         (one ``invoke_config_batch``/``config_surface`` call — counts
         against backend invocation tallies) to also report which
         candidates have unbounded (inf-runtime) failures; on the fast
-        plane those cells replay per-cell off the precomputed plan
+        plane those cells replay per-cell off the precomputed tables
         instead of the longest-path sweep. Purely diagnostic — no
         fleet is run."""
         config_sets = list(config_sets)
@@ -1610,7 +1568,7 @@ class FleetEngine:
                 out["reasons"].append(
                     f"candidates {bad} have unbounded (inf-runtime) "
                     "failures; their cells replay per-cell off the "
-                    "precomputed plan")
+                    "precomputed tables")
         return out
 
     def _candidate_arrays(self, template, config_sets):
@@ -1664,64 +1622,29 @@ class FleetEngine:
             wfs.append(wf)
         return self.run(wfs, times, carry=carry, collect_carry=collect_carry)
 
-    def _run_many_planned(self, template, config_sets, times_list, carry,
-                          collect_carry, names, runtimes, failed,
-                          noise, fstream, call) -> List[FleetReport]:
-        """Pricing model doesn't vectorize: replay every cell through
-        per-instance workflow copies so custom scalar ``function_cost``
-        sees real node objects — but drive the event loops off the
-        caller's ONE response-surface call instead of re-dispatching
-        into the backend per admission round."""
-        counts = [len(t) for t in times_list]
-        offsets = [0]
-        for c in counts:
-            offsets.append(offsets[-1] + c)
-        reports: List[FleetReport] = []
-        for ci, configs in enumerate(config_sets):
-            for si, times in enumerate(times_list):
-                with _cell_span(call, "planned"):
-                    reports.append(self._run_one_planned(
-                        template, configs, times, carry, collect_carry,
-                        names, runtimes[ci], failed[ci], noise, offsets[si],
-                        fstream))
-        return reports
-
-    def _run_one_planned(self, template, configs, times, carry,
-                         collect_carry, names, rt_row, failed_row, noise,
-                         offset, fstream=None) -> FleetReport:
-        """One cell replayed through the exact scalar event loop, with
-        the backend swapped for the precomputed (runtime, failed) plan.
-        Bit-identical to ``_run_one_serial`` for surface backends
-        (elementwise surface => same floats, same event bookkeeping);
-        the vehicle for cells that can't join a vectorized sweep
-        (single-instance cells, unbounded-failure candidates,
-        non-vectorizing pricing)."""
-        col = {name: i for i, name in enumerate(names)}
-        wfs = []
-        plan: Dict[int, Tuple[float, bool]] = {}
-        for i in range(len(times)):
-            wf = template.copy()
-            wf.apply_configs(configs)
-            if noise is None:
-                rt_i = rt_row
-            else:
-                rt_i = np.where(failed_row, rt_row,
-                                rt_row * noise[offset + i])
-            for name, node in wf.nodes.items():
-                v = col[name]
-                plan[id(node)] = (float(rt_i[v]), bool(failed_row[v]))
-            wfs.append(wf)
-        shadow = FleetEngine(_PlannedBackend(plan), pricing=self.pricing,
-                             cluster=self.cluster,
-                             cold_start=self.cold_start, scale=self.scale,
-                             faults=self.faults,
-                             resilience=self.resilience)
-        if fstream is not None:
-            # the cell reads the parent plane's ONE fault stream at its
-            # own instance-row offset instead of re-drawing per cell
-            shadow._fault_stream = (fstream, offset)
-        return shadow.run(wfs, times, carry=carry,
-                          collect_carry=collect_carry)
+    def _price_tables(self, runtimes, failed, cpu, mem, noise):
+        """The priced tables the fast and constrained planes replay from:
+        the call's (C, V) runtimes, masked by the replay noise when there
+        is any ((C, N, V) then), and their costs. A pricing model that
+        vectorizes fills the cost table in ONE ``cost_batch`` expression;
+        any other, by scalar ``function_cost`` per entry, bit for bit
+        what ``_price_batch`` computes for the same invocation (the
+        configs are quantized already, and ``ResourceConfig``
+        re-quantizes them unchanged)."""
+        if noise is not None:
+            # failing invocations keep their deterministic thrash time
+            # (the same masking StochasticBackend._noise_batch applies)
+            runtimes = np.where(failed[:, None, :], runtimes[:, None, :],
+                                runtimes[:, None, :] * noise[None, :, :])
+            cpu, mem = cpu[:, None, :], mem[:, None, :]
+        if self._pricing_vectorized:
+            return runtimes, self.pricing.cost_batch(runtimes, cpu, mem)
+        cpu, mem = (np.broadcast_to(a, runtimes.shape).ravel().tolist()
+                    for a in (cpu, mem))
+        cost = np.asarray([
+            self.pricing.function_cost(rt, ResourceConfig(cpu=c, mem=m))
+            for rt, c, m in zip(runtimes.ravel().tolist(), cpu, mem)])
+        return runtimes, cost.reshape(runtimes.shape)
 
     def _run_many_constrained(self, template, config_sets, times_list,
                               carry, collect_carry, names, cpu, mem,
@@ -1730,49 +1653,56 @@ class FleetEngine:
         """Finite-capacity / cold-start / carry-collecting cells: the
         exact scalar event loop, table-driven. The whole plane's
         runtimes come from the caller's ONE response-surface call and
-        are priced in ONE ``cost_batch`` expression here; the per-cell
-        loops then run pure-Python bookkeeping — zero backend or
-        pricing calls, zero template copies, zero per-instance object
-        churn inside the event loops."""
-        topo = self._topology_tables(template, names)
-        counts = [len(t) for t in times_list]
-        offsets = [0]
-        for c in counts:
-            offsets.append(offsets[-1] + c)
+        are priced into ONE cost table here; the per-cell loops then run
+        pure-Python bookkeeping — zero backend or pricing calls, zero
+        template copies, zero per-instance object churn inside the
+        event loops."""
         with telemetry.span("fleet.price", call=call):
-            if noise is None:
-                cost_plane = self.pricing.cost_batch(runtimes, cpu, mem)
-            else:
-                # failing invocations keep their deterministic thrash
-                # time (the same masking StochasticBackend._noise_batch
-                # applies)
-                rt_full = np.where(failed[:, None, :], runtimes[:, None, :],
-                                   runtimes[:, None, :] * noise[None, :, :])
-                cost_full = self.pricing.cost_batch(
-                    rt_full, cpu[:, None, :], mem[:, None, :])
+            rt_tab, cost_tab = self._price_tables(runtimes, failed, cpu,
+                                                  mem, noise)
+        cells = itertools.product(range(len(config_sets)),
+                                  range(len(times_list)))
+        return self._replay_cells(template, config_sets, times_list, cells,
+                                  carry, collect_carry, names, cpu, mem,
+                                  failed, rt_tab, cost_tab, fstream, call,
+                                  "constrained")
+
+    def _replay_cells(self, template, config_sets, times_list, cells,
+                      carry, collect_carry, names, cpu, mem, failed,
+                      rt_tab, cost_tab, fstream, call,
+                      plane) -> List[FleetReport]:
+        """Replay (candidate, arrival set) ``cells`` one at a time off
+        the priced tables, each by the route ``run`` takes: the
+        degenerate report for a fleet of one with no carry, the
+        table-driven event loop for the rest. The constrained plane
+        passes every cell; the fast plane, the cells its sweep cannot
+        take."""
+        topo = self._topology_tables(template, names)
+        offsets = [0, *itertools.accumulate(len(t) for t in times_list)]
+        noisy = rt_tab.ndim == 3
         reports: List[FleetReport] = []
-        for ci in range(len(config_sets)):
-            cpu_row = cpu[ci].tolist()
-            mem_row = mem[ci].tolist()
-            failed_row = failed[ci].tolist()
-            if noise is None:
-                # instances of one candidate share a row: alias it
-                rt_shared = runtimes[ci].tolist()
-                cost_shared = cost_plane[ci].tolist()
-            for si, times in enumerate(times_list):
-                m = counts[si]
-                if noise is None:
-                    rt_rows = [rt_shared] * m
-                    cost_rows = [cost_shared] * m
+        for ci, si in cells:
+            times = times_list[si]
+            m, lo = len(times), offsets[si]
+            with _cell_span(call, plane):
+                if self._takes_degenerate(m, carry, collect_carry):
+                    wf = template.copy()
+                    wf.apply_configs(config_sets[ci])
+                    reports.append(self._degenerate_report(
+                        wf, float(times[0]),
+                        rt_tab[ci, lo] if noisy else rt_tab[ci], failed[ci]))
+                    continue
+                if noisy:
+                    rt_rows = rt_tab[ci, lo:lo + m].tolist()
+                    cost_rows = cost_tab[ci, lo:lo + m].tolist()
                 else:
-                    seg = slice(offsets[si], offsets[si] + m)
-                    rt_rows = rt_full[ci, seg].tolist()
-                    cost_rows = cost_full[ci, seg].tolist()
-                with _cell_span(call, "constrained"):
-                    reports.append(self._run_cell_table(
-                        template, times, carry, collect_carry, names, topo,
-                        cpu_row, mem_row, rt_rows, [failed_row] * m,
-                        cost_rows, fstream, offsets[si]))
+                    # instances of one candidate share a row: alias it
+                    rt_rows = [rt_tab[ci].tolist()] * m
+                    cost_rows = [cost_tab[ci].tolist()] * m
+                reports.append(self._run_cell_table(
+                    template, times, carry, collect_carry, names, topo,
+                    cpu[ci].tolist(), mem[ci].tolist(), rt_rows,
+                    [failed[ci].tolist()] * m, cost_rows, fstream, lo))
         return reports
 
     def _topology_tables(self, template, names):
@@ -2065,32 +1995,13 @@ class FleetEngine:
         n_cand = len(config_sets)
         n_seeds = len(times_list)
         counts = [len(t) for t in times_list]
-        offsets = [0]
-        for c in counts:
-            offsets.append(offsets[-1] + c)
+        offsets = [0, *itertools.accumulate(counts)]
         finite = np.isfinite(runtimes).all(axis=1)
-
-        reports: List[Optional[FleetReport]] = [None] * (n_cand * n_seeds)
-        # a candidate with an unbounded (inf-runtime) failure kills its
-        # instances mid-flight — downstream work never runs, which the
-        # longest-path plane cannot express: those cells replay the
-        # exact event loop off the precomputed plan (no backend calls)
-        for ci in np.flatnonzero(~finite):
-            for si, times in enumerate(times_list):
-                with _cell_span(call, "fast"):
-                    reports[ci * n_seeds + si] = self._run_one_planned(
-                        template, config_sets[ci], times, carry, False,
-                        names, runtimes[ci], failed[ci], noise, offsets[si])
         live = np.flatnonzero(finite)
-        if not live.size:
-            return reports
-
-        rt = runtimes[live]                       # (C', V)
+        # the swept candidates' rows: views when every candidate sweeps
+        sel = slice(None) if live.size == n_cand else live
         col = {name: i for i, name in enumerate(names)}
         order = template.topological_order()
-        t_all = np.concatenate(times_list) if times_list else \
-            np.empty(0)
-        cand_failed = failed[live].any(axis=1)
 
         # per-candidate cost of one instance: executed invocations
         # summed in topological-rank order — the same left-to-right
@@ -2098,21 +2009,38 @@ class FleetEngine:
         # stochastic plane the cost gains an instance axis (noise is
         # per (instance, function), shared across candidates).
         with telemetry.span("fleet.price", call=call):
-            if noise is None:
-                node_cost = self.pricing.cost_batch(rt, cpu[live], mem[live])
-                cand_cost = np.zeros(live.size)
-                for name in order:
-                    cand_cost = cand_cost + node_cost[:, col[name]]
-                rt_col = lambda name: rt[:, col[name]][:, None]
-            else:
-                rt_eff = np.where(failed[live][:, None, :], rt[:, None, :],
-                                  rt[:, None, :] * noise[None, :, :])
-                node_cost = self.pricing.cost_batch(
-                    rt_eff, cpu[live][:, None, :], mem[live][:, None, :])
-                cand_cost = np.zeros((live.size, t_all.size))
-                for name in order:
-                    cand_cost = cand_cost + node_cost[:, :, col[name]]
-                rt_col = lambda name: rt_eff[:, :, col[name]]
+            rt_tab, cost_tab = self._price_tables(runtimes, failed, cpu,
+                                                  mem, noise)
+            node_cost = cost_tab[sel]
+            cand_cost = np.zeros(node_cost.shape[:-1])
+            for name in order:
+                cand_cost = cand_cost + node_cost[..., col[name]]
+            # (C', N, V), or (C', 1, V) where every instance shares a row
+            rt_eff = rt_tab[sel] if noise is not None \
+                else rt_tab[sel][:, None, :]
+
+        # the cells the sweep cannot take replay on their own: a
+        # candidate with an unbounded (inf-runtime) failure kills its
+        # instances mid-flight — downstream work never runs, which the
+        # longest-path plane cannot express — and a fleet of one takes
+        # ``run``'s degenerate path (or, with a carry, its event loop),
+        # whose float associations differ from the absolute-time plane
+        # in the last bits
+        reports: List[Optional[FleetReport]] = [None] * (n_cand * n_seeds)
+        alone = [(ci, si) for ci in range(n_cand) for si in range(n_seeds)
+                 if not finite[ci] or counts[si] == 1]
+        if alone:
+            for (ci, si), report in zip(alone, self._replay_cells(
+                    template, config_sets, times_list, alone, carry, False,
+                    names, cpu, mem, failed, rt_tab, cost_tab, None, call,
+                    "fast")):
+                reports[ci * n_seeds + si] = report
+        if not live.size:
+            return reports
+
+        rt = runtimes[sel]                        # (C', V)
+        t_all = np.concatenate(times_list)
+        cand_failed = failed[sel].any(axis=1)
 
         # shared event skeleton: absolute finish of node v for every
         # (candidate, instance) — sources start at the arrival instant,
@@ -2141,7 +2069,7 @@ class FleetEngine:
                         # decouple from arrival order
                         start_by_node[name] = np.broadcast_to(
                             start, (live.size, t_all.size))
-                    finish_by_node[name] = start + rt_col(name)
+                    finish_by_node[name] = start + rt_eff[:, :, col[name]]
                 inst_finish = None
                 for arr in finish_by_node.values():
                     inst_finish = arr if inst_finish is None \
@@ -2174,24 +2102,13 @@ class FleetEngine:
                 telemetry.count("fleet.ledger.rows.folded", int(bits.size))
             for si, times in enumerate(times_list):
                 m = counts[si]
+                if m == 1:
+                    continue                  # replayed on its own above
                 seg = slice(offsets[si], offsets[si] + m)
                 for k, ci in enumerate(live):
                     idx = int(ci) * n_seeds + si
                     if m == 0:
                         reports[idx] = self._empty_report()
-                        continue
-                    if m == 1:
-                        # a fleet of one takes ``run``'s degenerate fast
-                        # path, whose float associations (relative
-                        # longest-path shifted by the arrival, cost in
-                        # node-insertion order) differ from the absolute-
-                        # time plane in the last bits — replay the cell off
-                        # the plan to keep the bit-identity contract
-                        with _cell_span(call, "fast"):
-                            reports[idx] = self._run_one_planned(
-                                template, config_sets[ci], times, carry,
-                                False, names, runtimes[ci], failed[ci],
-                                noise, offsets[si])
                         continue
                     t0 = float(times.min())
                     t_last = float(inst_finish[k, seg].max())
@@ -2279,6 +2196,17 @@ class FleetEngine:
                 return np.asarray(fin)
 
     # -- internals -----------------------------------------------------
+    def _takes_degenerate(self, n: int, carry: Optional[FleetCarry],
+                          collect_carry: bool) -> bool:
+        """Does ``run`` take its degenerate path for a fleet of ``n``?
+        Yes for a fleet of one with nothing carried in or out, on an
+        infinite cluster with zero cold start, no replica pools and no
+        faults: no contention, so runtimes are schedule-independent."""
+        return (n == 1 and carry is None and not collect_carry
+                and not self.cluster.finite
+                and self.cold_start.delay_s == 0.0
+                and self.scale is None and self.faults is None)
+
     def _run_degenerate(self, wf: Workflow, arrival: float) -> FleetReport:
         """Fleet of 1 / infinite capacity / zero cold start: equivalent
         to the event loop (verified by tests) at scalar-path speed."""
@@ -2288,6 +2216,15 @@ class FleetEngine:
             runtimes = np.asarray(runtimes, dtype=np.float64) * \
                 np.asarray([self.interference.get((wf.identity, n.name), 1.0)
                             for n in nodes])
+        return self._degenerate_report(wf, arrival, runtimes, failed)
+
+    def _degenerate_report(self, wf: Workflow, arrival: float, runtimes,
+                           failed) -> FleetReport:
+        """The degenerate path's report from ``wf``'s runtimes and
+        failure flags in node order: written onto the nodes, priced by
+        scalar ``function_cost`` in node order, and timed by the
+        workflow's relative longest path shifted by the arrival."""
+        nodes = list(wf)
         cost = 0.0
         busy: Dict[str, float] = {}
         for node, rt, bad in zip(nodes, runtimes, failed):

@@ -37,7 +37,7 @@ of that story:
 Recovery semantics are inert without a fault model: a
 ``FleetEngine(resilience=..., faults=None)`` run is bit-identical to a
 plain engine (there is nothing to recover from), and ``faults=None``
-pins the engine bit-identical to its pre-fault behaviour on all four
+pins the engine bit-identical to its pre-fault behaviour on all three
 replay planes.
 """
 from __future__ import annotations

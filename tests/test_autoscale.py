@@ -12,8 +12,9 @@ controller and the autoscale benchmark):
     the R latest-expiring containers at load (the mid-sequence
     replica-change handoff);
   * an *ample* pool at zero provisioning price is **bit-identical** to
-    ``scale=None`` on all four replay planes (fast / constrained /
-    planned / serial) — the actuator is purely additive;
+    ``scale=None`` on all three replay planes (fast / constrained /
+    serial) and under a scalar-only pricing model — the actuator is
+    purely additive;
   * provisioned replica-seconds are billed, so scale-out is never free.
 
 Plus the joint-search surface (:class:`ScaleSearcher` speaks the
@@ -143,11 +144,12 @@ def test_saturation_reports_pool_diagnostics():
     assert row["queue_share"] == 1.0          # the only queued function
 
 
-# -- ample-pool bit-identity on all four replay planes ------------------
+# -- ample-pool bit-identity on all three replay planes -----------------
 
 class _ScalarMirrorPricing(PricingModel):
-    """Same numbers, no vectorized ``cost_batch``: forces the planned
-    plane (mirrors the idiom pinned in test_replay_batch)."""
+    """Same numbers, no vectorized ``cost_batch``: the cost table is
+    filled entry by entry on whichever plane the replay routes to
+    (mirrors the idiom pinned in test_replay_batch)."""
 
     def function_cost(self, runtime_s, config):
         return super().function_cost(runtime_s, config)
@@ -169,7 +171,7 @@ def _plane_engine(plane, scale):
                                                 total_mem_mb=16384.0),
                            cold_start=ColdStartModel(delay_s=1.0,
                                                      keep_alive_s=30.0))
-    if plane == "planned":
+    if plane == "scalar_pricing":
         return FleetEngine(env.backend, pricing=_ScalarMirrorPricing(),
                            scale=scale)
     assert plane == "serial"
@@ -189,7 +191,7 @@ def _assert_reports_identical(got, want):
     assert got.queue_delay_by_function == want.queue_delay_by_function
 
 
-@pytest.mark.parametrize("plane", ["fast", "constrained", "planned",
+@pytest.mark.parametrize("plane", ["fast", "constrained", "scalar_pricing",
                                    "serial"])
 def test_ample_pool_is_bit_identical_to_scale_none_on_every_plane(plane):
     """The acceptance bar: an ample zero-price ReplicaModel reproduces

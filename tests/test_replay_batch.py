@@ -18,7 +18,7 @@ import pytest
 
 from repro.core import engine as engine_module
 from repro.core import telemetry
-from repro.core.backend import CallableBackend
+from repro.core.backend import BaseBackend, CallableBackend
 from repro.core.cost import PricingModel
 from repro.core.engine import (ClusterModel, ColdStartModel, FleetCarry,
                                FleetEngine, PoissonArrivals, _fold_repeats,
@@ -212,9 +212,9 @@ def test_run_many_uses_the_vectorized_plane():
 
 class _ScalarMirrorPricing(PricingModel):
     """Overrides scalar ``function_cost`` with the *same* values but no
-    matching ``cost_batch``: routes replays onto the planned plane (the
-    exact per-instance event loop driven off the precomputed runtime
-    plan) without changing any number."""
+    matching ``cost_batch``: replays route as with any pricing model,
+    and the cost table is filled entry by entry through scalar
+    ``function_cost`` — without changing any number."""
 
     def function_cost(self, runtime_s, config):
         return super().function_cost(runtime_s, config)
@@ -247,24 +247,61 @@ def test_run_many_stochastic_same_config_scores_identically(engine_kw):
     assert_reports_identical(reports[1], reports[3])
 
 
+class _PlanBackend(BaseBackend):
+    """Replays a fixed ``(runtime, failed)`` per node object, so
+    ``FleetEngine.run``'s event loop can be driven off a plan drawn
+    elsewhere (here: one paired replay-noise draw)."""
+
+    deterministic = True
+
+    def __init__(self, plan):
+        self._plan = plan
+
+    def invoke_batch(self, nodes):
+        rows = [self._plan[id(node)] for node in nodes]
+        return (np.array([rt for rt, _ in rows], dtype=np.float64),
+                np.array([bad for _, bad in rows], dtype=bool))
+
+
 @pytest.mark.parametrize("engine_kw", [{}, CONSTRAINED_KW],
                          ids=["fast_plane", "constrained_plane"])
 def test_run_many_stochastic_matches_planned_event_loop(engine_kw):
     """Cross-plane bit-identity under noise: the vectorized planes must
-    reproduce the exact per-instance event loop replaying the same
-    plan. ``_ScalarMirrorPricing`` computes identical costs but forces
-    the planned (event-loop) plane; both engines draw the identical
-    noise tensor (same backend seed, ONE replay_noise advance per
-    plane), so every compared field must agree bit-for-bit."""
+    reproduce ``FleetEngine.run``'s event loop over template copies
+    replaying the same plan — the noise-free surface times ONE
+    same-seed ``replay_noise`` draw (failures keep their thrash time),
+    instance by instance — on every compared field, bit for bit."""
     template = TOPOLOGIES["layered"]()
     cands = candidate_sets(template, 3, seed=7)
     seeds = arrival_sets(2)
     vec = _stochastic_engine(99, **engine_kw).run_many(
         template, cands, seeds)
-    ref = _stochastic_engine(99, pricing=_ScalarMirrorPricing(),
-                             **engine_kw).run_many(template, cands, seeds)
-    for got, want in zip(vec, ref):
-        assert_reports_identical(got, want)
+    backend = StochasticBackend(noise_sigma=0.05, seed=99)
+    nodes = list(template)
+    cpu = np.array([[c[n.name].cpu for n in nodes] for c in cands])
+    mem = np.array([[c[n.name].mem for n in nodes] for c in cands])
+    runtimes, failed = backend.config_surface(nodes, cpu, mem)
+    noise = backend.replay_noise(sum(len(t) for t in seeds), len(nodes))
+    k = 0
+    for ci, configs in enumerate(cands):
+        row = 0
+        for times in seeds:
+            plan, wfs = {}, []
+            for _ in times:
+                wf = template.copy()
+                wf.apply_configs(configs)
+                rt = np.where(failed[ci], runtimes[ci],
+                              runtimes[ci] * noise[row])
+                for v, node in enumerate(wf):
+                    plan[id(node)] = (float(rt[v]), bool(failed[ci, v]))
+                wfs.append(wf)
+                row += 1
+            ref = FleetEngine(_PlanBackend(plan),
+                              pricing=SimulatedPlatform().pricing,
+                              **engine_kw).run(wfs, times)
+            assert_reports_identical(vec[k], ref)
+            k += 1
+    assert k == len(vec) == 6
 
 
 def test_run_many_stochastic_replay_is_reproducible_and_noisy():
@@ -313,7 +350,7 @@ class _NoClampBackend(AnalyticBackend):
     """Deterministic surface whose failures are unbounded (+inf): a
     dead instance never runs its downstream nodes, which the fast
     plane's longest-path sweep cannot see — those candidates replay
-    per-cell off the precomputed plan (the constrained plane handles
+    per-cell off the precomputed tables (the constrained plane handles
     them natively)."""
 
     has_clamped = False
@@ -371,13 +408,18 @@ def test_opaque_callable_backend_falls_back_and_matches():
                           arrival_sets(2))
 
 
-def test_run_many_single_instance_cell_matches_degenerate_path():
-    """A fleet of one goes through ``run``'s degenerate fast path,
-    whose float associations differ from the absolute-time plane —
-    run_many replays that cell off the precomputed plan (through the
-    same degenerate path) to stay bit-identical. Uses a
-    template whose insertion order differs from topological order so
-    any accumulation-order divergence would surface."""
+@pytest.mark.parametrize("carry", [
+    None, FleetCarry(clock=0.0, warm={}, busy=[(900.0, 2.0, 512.0)])],
+    ids=["no_carry", "live_reservation"])
+def test_run_many_single_instance_cell_matches_degenerate_path(carry):
+    """A fleet of one with no carry goes through ``run``'s degenerate
+    fast path, whose float associations differ from the absolute-time
+    plane — run_many builds that cell's report through the same
+    degenerate path to stay bit-identical; with a carry, ``run`` takes
+    its event loop and so does the cell. Healthy candidates and one
+    with an unbounded failure, on a template whose insertion order
+    differs from topological order so any accumulation-order
+    divergence would surface."""
     from repro.core.dag import Workflow
     from repro.serverless.generator import random_spec
 
@@ -387,12 +429,19 @@ def test_run_many_single_instance_cell_matches_degenerate_path():
         template.add_function(name, payload=random_spec(name, rng))
     template.add_edge("f0", "f1")
     template.add_edge("f1", "f2")
-    engine = make_engine()
-    cands = candidate_sets(template, 2, seed=10)
+    engine = FleetEngine(_NoClampBackend(),
+                         pricing=SimulatedPlatform().pricing)
+    healthy = {n.name: ResourceConfig(cpu=4.0, mem=8192.0) for n in template}
+    dying = {n.name: ResourceConfig(cpu=4.0, mem=128.0) for n in template}
+    cands = [healthy, *candidate_sets(template, 2, seed=10), dying]
     # nonzero arrival: the degenerate path computes e2e relative and
     # shifts by the arrival, unlike the absolute event-time chain
-    assert_grid_identical(engine, template, cands,
-                          [np.array([13.7])])
+    reports = assert_grid_identical(engine, template, cands,
+                                    [np.array([13.7])], carry=carry)
+    assert not reports[0].failed_mask.any()
+    assert reports[-1].failed_mask.all() and math.isinf(reports[-1].p99)
+    # the live reservation releases inside the run and ends it
+    assert all((r.makespan > 800.0) == (carry is not None) for r in reports)
 
 
 def test_custom_pricing_overrides_are_honored():
@@ -424,6 +473,14 @@ def test_custom_pricing_overrides_are_honored():
     assert vec._pricing_vectorized
     got_vec = vec.run_many(template, cands, [times])[0]
     assert got_vec.total_cost == pytest.approx(got.total_cost)
+    # scalar pricing fills the cost table entry by entry, on the fast
+    # and the constrained plane alike: bit for bit the serial reference
+    for pricing in (_ScalarMirrorPricing(), DoubledPricing()):
+        for engine_kw in ({}, CONSTRAINED_KW):
+            engine = FleetEngine(env.backend, pricing=pricing, **engine_kw)
+            assert_grid_identical(engine, template,
+                                  candidate_sets(template, 2, seed=11),
+                                  arrival_sets(2))
 
 
 def test_online_stochastic_validation_stays_paired():
@@ -491,17 +548,26 @@ def test_run_many_collect_carry_matches_scalar():
             k += 1
 
 
-def test_run_many_one_surface_one_pricing_call_on_constrained_plane():
+@pytest.mark.parametrize("vectorized", [True, False],
+                         ids=["cost_batch", "scalar_pricing"])
+def test_run_many_one_surface_one_pricing_call_on_constrained_plane(
+        vectorized):
     """The constrained plane's whole C×S grid must cost ONE
     ``invoke_config_batch`` and ONE ``cost_batch`` — the per-cell event
     loops run off the precomputed tables with zero backend/pricing
-    dispatch."""
-    calls = {"cost": 0}
+    dispatch. A pricing model that does not vectorize fills the same
+    table by one scalar ``function_cost`` per (candidate, function)."""
+    calls = {"cost": 0, "scalar": 0}
 
     class CountingPricing(PricingModel):
         def cost_batch(self, runtime_s, cpu, mem):
             calls["cost"] += 1
             return super().cost_batch(runtime_s, cpu, mem)
+
+    class CountingScalarPricing(PricingModel):
+        def function_cost(self, runtime_s, config):
+            calls["scalar"] += 1
+            return super().function_cost(runtime_s, config)
 
     template = TOPOLOGIES["layered"]()
     env = SimulatedPlatform().environment()
@@ -513,15 +579,18 @@ def test_run_many_one_surface_one_pricing_call_on_constrained_plane():
     env.backend.invoke_batch = \
         lambda *a, **k: pytest.fail("scalar invoke_batch on the "
                                     "constrained plane")
-    engine = FleetEngine(env.backend, pricing=CountingPricing(),
+    pricing = CountingPricing() if vectorized else CountingScalarPricing()
+    engine = FleetEngine(env.backend, pricing=pricing,
                          cluster=ClusterModel(total_cpu=14.0,
                                               total_mem_mb=20480.0),
                          cold_start=ColdStartModel(delay_s=0.5,
                                                    keep_alive_s=60.0))
+    assert engine.batch_eligibility(template, [])["plane"] == "constrained"
     reports = engine.run_many(template, candidate_sets(template, 4, seed=14),
                               arrival_sets(3, rate=1.0))
     assert surface["n"] == 1
-    assert calls["cost"] == 1
+    assert calls == ({"cost": 1, "scalar": 0} if vectorized
+                     else {"cost": 0, "scalar": 4 * len(template)})
     assert len(reports) == 12
     assert any(r.total_queue_delay > 0.0 for r in reports)
 
@@ -547,13 +616,13 @@ def test_batch_eligibility_reports_plane_routing():
     assert carry_plane["plane"] == "constrained"
     assert any("collect_carry" in r for r in carry_plane["reasons"])
 
+    # the pricing model never routes a replay
     env = SimulatedPlatform().environment()
-    planned = FleetEngine(env.backend,
-                          pricing=_ScalarMirrorPricing()).batch_eligibility(
+    scalar = FleetEngine(env.backend,
+                         pricing=_ScalarMirrorPricing()).batch_eligibility(
         template, [])
-    assert planned["plane"] == "planned"
-    assert not planned["vectorized"]
-    assert any("pricing" in r for r in planned["reasons"])
+    assert scalar == {"plane": "fast", "vectorized": True, "reasons": [],
+                      "serial_candidates": None}
 
     opaque = FleetEngine(CallableBackend(lambda node: 0.1),
                          pricing=env.pricing).batch_eligibility(template, [])
@@ -636,7 +705,7 @@ def test_pricing_vectorization_redetects_after_swap_and_mutation():
     assert engine._pricing_vectorized
 
     # and the verdict is honored end to end: the zero-cost mutant
-    # prices every replay at exactly zero via the planned plane
+    # prices every replay at exactly zero through the cost table
     Mutant.function_cost = lambda self, runtime_s, config: 0.0
     template = TOPOLOGIES["chain"]()
     report = engine.run_many(template, candidate_sets(template, 1, seed=15),

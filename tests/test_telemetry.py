@@ -34,7 +34,8 @@ CONSTRAINED_KW = dict(cluster=ClusterModel(total_cpu=12.0,
 
 class _ScalarPricing(PricingModel):
     """The same prices through a scalar override with no matching
-    ``cost_batch``: routes replays onto the planned plane."""
+    ``cost_batch``: the fast plane fills its cost table entry by
+    entry."""
 
     def function_cost(self, runtime_s, config):
         return super().function_cost(runtime_s, config)
@@ -48,7 +49,7 @@ def _engine(plane: str) -> FleetEngine:
     if plane == "constrained":
         return FleetEngine(env.backend, pricing=env.pricing,
                            **CONSTRAINED_KW)
-    if plane == "planned":
+    if plane == "scalar_pricing":
         return FleetEngine(env.backend, pricing=_ScalarPricing())
     if plane == "stochastic":
         # replay noise: the fast plane with the numpy sweep
@@ -176,12 +177,12 @@ def test_each_run_many_is_one_span_with_its_phases_inside(plane):
             assert {k[3]["plane"] for k in cells} == {"constrained"}
 
 
-@pytest.mark.parametrize("plane", ["fast", "constrained", "planned",
+@pytest.mark.parametrize("plane", ["fast", "constrained", "scalar_pricing",
                                    "serial", "stochastic"])
 def test_counters_name_the_plane_batch_eligibility_reports(plane):
     template, cands, seeds = _inputs()
     engine = _engine(plane)
-    routed = "fast" if plane == "stochastic" else plane
+    routed = "fast" if plane in ("stochastic", "scalar_pricing") else plane
     assert engine.batch_eligibility(template, cands)["plane"] == routed
     before = telemetry.counters()
     engine.run_many(template, cands, seeds)
