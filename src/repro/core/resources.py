@@ -12,7 +12,9 @@ independently of each other.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Callable, Dict, Tuple
+
+from repro.core import telemetry
 
 MEM_MIN_MB = 128.0
 MEM_MAX_MB = 10240.0
@@ -37,16 +39,54 @@ def quantize_cpu(cpu: float) -> float:
     return round(cpu / CPU_STEP) * CPU_STEP
 
 
-@dataclasses.dataclass
+#: the most entries each memo of quantized values holds; past it a new
+#: value is quantized and not stored (the lattice has 100 vCPU and 159
+#: MB points)
+_MEMO_ENTRIES = 4096
+#: a float the caller gave -> ``quantize_cpu`` / ``quantize_mem`` of it
+_CPU_MEMO: Dict[float, float] = {}
+_MEM_MEMO: Dict[float, float] = {}
+
+
+def _memo_miss(x: float, quantize: Callable[[float], float],
+               memo: Dict[float, float]) -> float:
+    telemetry.count("resources.quantize.misses")
+    q = quantize(x)                    # NaN raises here, before storing
+    if len(memo) < _MEMO_ENTRIES:
+        memo[x] = q
+    return q
+
+
+@dataclasses.dataclass(slots=True)
 class ResourceConfig:
-    """A decoupled (vCPU, memory-MB) allocation for one function."""
+    """A decoupled (vCPU, memory-MB) allocation for one function.
+
+    Built on the lattice: each value is what ``quantize_cpu`` /
+    ``quantize_mem`` returns for it, of the same type. A ``float`` is
+    looked up in a memo of the values seen before (only ``±0.0`` are
+    distinct floats that share a key, and both clamp to the floor);
+    any other type (int, bool, ``np.float64``) is quantized anew.
+    Attributes set after construction are not quantized.
+    """
 
     cpu: float = CPU_MAX
     mem: float = MEM_MAX_MB
 
-    def __post_init__(self) -> None:
-        self.cpu = quantize_cpu(self.cpu)
-        self.mem = quantize_mem(self.mem)
+    def __init__(self, cpu: float = CPU_MAX, mem: float = MEM_MAX_MB) -> None:
+        if type(cpu) is float:
+            q = _CPU_MEMO.get(cpu)
+            if q is None:
+                q = _memo_miss(cpu, quantize_cpu, _CPU_MEMO)
+        else:
+            q = quantize_cpu(cpu)
+        self.cpu = q
+        if type(mem) is float:
+            q = _MEM_MEMO.get(mem)
+            if q is None:
+                q = _memo_miss(mem, quantize_mem, _MEM_MEMO)
+        else:
+            q = quantize_mem(mem)
+        self.mem = q
 
     def copy(self) -> "ResourceConfig":
         return ResourceConfig(cpu=self.cpu, mem=self.mem)

@@ -14,3 +14,13 @@ import pytest
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def fresh_memo(monkeypatch):
+    """Empty memos of quantized values (``repro.core.resources``),
+    whatever earlier tests stored."""
+    from repro.core import resources
+
+    monkeypatch.setattr(resources, "_CPU_MEMO", {})
+    monkeypatch.setattr(resources, "_MEM_MEMO", {})

@@ -11,19 +11,24 @@ paired replay-stream contract — stochastic backends, where the
 vectorized planes must match the exact event loop replaying the same
 noise plan.
 """
+import copy
+import dataclasses
 import math
+import pickle
+from decimal import Decimal
 
 import numpy as np
 import pytest
 
 from repro.core import engine as engine_module
-from repro.core import telemetry
+from repro.core import resources, telemetry
 from repro.core.backend import BaseBackend, CallableBackend
 from repro.core.cost import PricingModel
 from repro.core.engine import (ClusterModel, ColdStartModel, FleetCarry,
                                FleetEngine, PoissonArrivals, _fold_repeats,
                                _fold_rows)
-from repro.core.resources import CPU_STEP, ResourceConfig
+from repro.core.resources import (BASE_CONFIG, CPU_STEP, MEM_STEP_MB,
+                                  ResourceConfig, quantize_cpu, quantize_mem)
 from repro.serverless.generator import (chain_workflow, diamond_workflow,
                                         fan_workflow, layered_workflow)
 from repro.serverless.platform import (AnalyticBackend, SimulatedPlatform,
@@ -954,6 +959,154 @@ def test_candidate_arrays_quantize_as_each_config_copy_does():
             want = configs.get(node.name, node.config).copy()
             assert cpu[ci, vi].hex() == float(want.cpu).hex()
             assert mem[ci, vi].hex() == float(want.mem).hex()
+
+
+_CPU_LATTICE = [k * CPU_STEP for k in range(1, 101)]
+_MEM_LATTICE = [k * MEM_STEP_MB for k in range(2, 161)]
+#: (vCPU values, MB values) per case
+_QUANTIZE_GRID = {
+    # built the way the benchmark's generator builds them
+    "lattice": (_CPU_LATTICE, _MEM_LATTICE),
+    "half_steps": ([(k + 0.5) * CPU_STEP for k in range(0, 101)],
+                   [(k + 0.5) * MEM_STEP_MB for k in range(1, 161)]),
+    "off_lattice": ([k * CPU_STEP + 0.03 for k in range(1, 100)]
+                    + np.random.default_rng(31).uniform(0.1, 10.0,
+                                                        64).tolist(),
+                    [k * MEM_STEP_MB + 7.5 for k in range(2, 160)]
+                    + np.random.default_rng(32).uniform(128.0, 10240.0,
+                                                        64).tolist()),
+    "beyond_ends": ([-5.0, 0.0, 0.04, 0.0999, 10.04, 10.06, 1e300],
+                    [-64.0, 0.0, 95.9, 127.9, 10271.9, 10272.1, 1e300]),
+    "infinities": ([math.inf, -math.inf], [math.inf, -math.inf]),
+    # -0.0 == 0.0 shares a key with 0.0: both clamp to the floor
+    "negative_zero": ([-0.0, 0.0, -0.0], [-0.0, 0.0, -0.0]),
+    "ints": ([-3, 0, 1, 3, 7, 10, 11, 2**70],
+             [-1, 0, 100, 128, 1000, 1024, 10240, 20000, 2**70]),
+    "bools": ([True, False], [True, False]),
+    "np_float64": ([np.float64(x) for x in (-1.0, 0.05, 0.25, 2.0, 5.6,
+                                            9.95, 12.0, math.inf)],
+                   [np.float64(x) for x in (-1.0, 96.0, 160.0, 3072.0,
+                                            3103.9, 10400.0, math.inf)]),
+}
+
+
+@pytest.mark.parametrize("case", list(_QUANTIZE_GRID))
+def test_resource_config_holds_what_quantize_returns(case, fresh_memo):
+    """Every value is ``quantize_cpu`` / ``quantize_mem`` of what the
+    caller gave, bit for bit and of the same type: when first seen (a
+    memo miss) and when seen again (a hit). Only floats are kept."""
+    cpus, mems = _QUANTIZE_GRID[case]
+    pairs = [(c, mems[i % len(mems)]) for i, c in enumerate(cpus)] + \
+        [(cpus[i % len(cpus)], m) for i, m in enumerate(mems)]
+    for _ in range(2):
+        for c, m in pairs:
+            cfg = ResourceConfig(c, m)
+            for got, want in ((cfg.cpu, quantize_cpu(c)),
+                              (cfg.mem, quantize_mem(m))):
+                assert type(got) is type(want)
+                assert float(got).hex() == float(want).hex()
+    floats = case not in ("ints", "bools", "np_float64")
+    assert bool(resources._CPU_MEMO) == floats
+    assert bool(resources._MEM_MEMO) == floats
+
+
+@pytest.mark.parametrize("resource", ["cpu", "mem"])
+def test_resource_config_nan_raises_and_is_not_kept(resource, fresh_memo):
+    # the other resource an int, which no memo keeps
+    args = {"cpu": 2, "mem": 1024, resource: math.nan}
+    before = telemetry.counters().get("resources.quantize.misses", 0)
+    for _ in range(2):
+        with pytest.raises(ValueError):
+            ResourceConfig(**args)
+    assert resources._CPU_MEMO == {} and resources._MEM_MEMO == {}
+    # a value that raises is looked up, and missed, each time
+    assert telemetry.counters()["resources.quantize.misses"] == before + 2
+
+
+
+@pytest.mark.parametrize("resource", ["cpu", "mem"])
+def test_resource_config_decimal_behaves_as_quantize_does(resource,
+                                                          fresh_memo):
+    """A ``Decimal`` in range raises, as ``quantize_*`` does; one
+    clamped to a float bound is that bound. No memo keeps either."""
+    quantize = {"cpu": quantize_cpu, "mem": quantize_mem}[resource]
+    before = telemetry.counters().get("resources.quantize.misses", 0)
+    for x in ("-1", "2", "2048", "1e9"):
+        args = {"cpu": 2, "mem": 1024, resource: Decimal(x)}
+        try:
+            want = quantize(Decimal(x))
+        except TypeError:
+            with pytest.raises(TypeError):
+                ResourceConfig(**args)
+        else:
+            got = getattr(ResourceConfig(**args), resource)
+            assert type(got) is type(want) and got.hex() == want.hex()
+    assert resources._CPU_MEMO == {} and resources._MEM_MEMO == {}
+    assert telemetry.counters().get("resources.quantize.misses", 0) == before
+
+
+def test_resource_config_type_surface():
+    """A slotted dataclass: the generated comparison, repr, fields,
+    asdict, replace and match args; copies and pickles; no
+    ``__dict__``; built by position or keyword."""
+    cfg = ResourceConfig(2.0, 1024.0)
+    assert cfg == ResourceConfig(cpu=2.0, mem=1024.0) == \
+        ResourceConfig(2.04, 1040.0)
+    assert cfg != ResourceConfig(2.1, 1024.0)
+    assert repr(cfg) == "ResourceConfig(cpu=2.0, mem=1024.0)"
+    assert str(cfg) == "(2.0 vCPU, 1024 MB)"
+    assert ResourceConfig() == BASE_CONFIG == ResourceConfig(10.0, 10240.0)
+    assert [f.name for f in dataclasses.fields(cfg)] == ["cpu", "mem"]
+    assert dataclasses.asdict(cfg) == {"cpu": 2.0, "mem": 1024.0}
+    moved = dataclasses.replace(cfg, cpu=3.33)
+    assert moved.cpu == quantize_cpu(3.33) and moved.mem == 1024.0
+    assert ResourceConfig.__match_args__ == ("cpu", "mem")
+    match cfg:
+        case ResourceConfig(c, m):
+            assert (c, m) == (2.0, 1024.0)
+    assert not hasattr(cfg, "__dict__")
+    with pytest.raises(AttributeError):
+        cfg.gpu = 1
+    for twin in (copy.copy(cfg), copy.deepcopy(cfg),
+                 pickle.loads(pickle.dumps(cfg)), cfg.copy()):
+        assert twin == cfg and twin is not cfg
+    assert cfg.__hash__ is None                 # mutable, as before
+
+
+def test_resource_config_attributes_set_later_are_not_quantized():
+    cfg = ResourceConfig(2.0, 1024.0)
+    cfg.cpu, cfg.mem = 2.03, 1000.5
+    assert (cfg.cpu, cfg.mem) == (2.03, 1000.5)
+    assert pickle.loads(pickle.dumps(cfg)).as_tuple() == (2.03, 1000.5)
+    assert copy.deepcopy(cfg).as_tuple() == (2.03, 1000.5)
+    # a copy is built anew, so it is quantized
+    assert cfg.copy().as_tuple() == (quantize_cpu(2.03), quantize_mem(1000.5))
+
+
+def test_quantize_memo_never_grows_past_its_bound(fresh_memo):
+    """Fed more distinct floats than it holds, each memo stops at its
+    bound; the values past it are still right, and each of their
+    lookups is a miss."""
+    n = resources._MEMO_ENTRIES + 300
+    cpus = np.linspace(0.0, 11.0, n).tolist()
+    mems = np.linspace(0.0, 11000.0, n).tolist()
+
+    def counted():
+        return telemetry.counters()["resources.quantize.misses"]
+
+    for _ in range(2):
+        for c, m in zip(cpus, mems):
+            cfg = ResourceConfig(c, m)
+            assert cfg.cpu.hex() == quantize_cpu(c).hex()
+            assert cfg.mem.hex() == quantize_mem(m).hex()
+        assert len(resources._CPU_MEMO) == resources._MEMO_ENTRIES
+        assert len(resources._MEM_MEMO) == resources._MEMO_ENTRIES
+    before = counted()
+    ResourceConfig(cpus[0], mems[0])            # stored
+    assert counted() == before
+    ResourceConfig(cpus[-1], mems[-1])          # past the bound
+    assert counted() == before + 2
+    assert cpus[-1] not in resources._CPU_MEMO
 
 
 def test_jax_plane_names_numpy_sweep_under_replay_noise():

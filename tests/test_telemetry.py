@@ -179,8 +179,14 @@ def test_each_run_many_is_one_span_with_its_phases_inside(plane):
 
 @pytest.mark.parametrize("plane", ["fast", "constrained", "scalar_pricing",
                                    "serial", "stochastic"])
-def test_counters_name_the_plane_batch_eligibility_reports(plane):
+def test_counters_name_the_plane_batch_eligibility_reports(plane,
+                                                         fresh_memo):
     template, cands, seeds = _inputs()
+    # the quantized values a plane rebuilds configs from, seen once
+    # before the call: it then misses no memo of them
+    for configs in cands:
+        for cfg in configs.values():
+            cfg.copy()
     engine = _engine(plane)
     routed = "fast" if plane in ("stochastic", "scalar_pricing") else plane
     assert engine.batch_eligibility(template, cands)["plane"] == routed
@@ -228,6 +234,47 @@ def test_a_new_sweep_shape_is_counted_once():
     engine.run_many(template, cands, seeds)
     engine.run_many(template, cands, seeds)
     assert telemetry.counters()["fleet.sweep.shapes"] == before + 1
+
+
+def test_a_quantized_value_is_missed_once_then_memoized(fresh_memo):
+    """``resources.quantize.misses`` counts a float's first sight by
+    each resource, not a repeat; over two ``run_many`` calls of the
+    same ``video.fast``-shaped candidates (the incumbent and challengers
+    moving 2 of 6 functions on the lattice) the second adds none."""
+    def misses():
+        return telemetry.counters().get("resources.quantize.misses", 0)
+
+    before = misses()
+    ResourceConfig(cpu=2.5, mem=2048.0)
+    assert misses() == before + 2
+    ResourceConfig(cpu=2.5, mem=2048.0)
+    ResourceConfig(cpu=2.5, mem=2.5)        # a value seen by cpu only
+    assert misses() == before + 3
+    ResourceConfig(cpu=2, mem=np.float64(2048.0))   # kept by no memo
+    assert misses() == before + 3
+
+    template = fan_workflow(4, seed=12)
+    names = list(template.nodes)
+    rng = np.random.default_rng(7)
+    cpu_k = np.full((8, len(names)), 80)
+    mem_k = np.full((8, len(names)), 80)
+    for c in range(1, 8):
+        moved = rng.choice(len(names), size=2, replace=False)
+        cpu_k[c, moved] += rng.integers(-10, 11, size=2)
+        mem_k[c, moved] += rng.integers(-8, 9, size=2)
+    cpu, mem = (cpu_k * 0.1).tolist(), (mem_k * 64.0).tolist()
+    _, _, seeds = _inputs(n_seeds=1, n=64)
+    engine = _engine("fast")
+    added = []
+    for _ in range(2):
+        before = misses()
+        cands = [{n: ResourceConfig(cpu=c, mem=m)
+                  for n, c, m in zip(names, cr, mr)}
+                 for cr, mr in zip(cpu, mem)]
+        engine.run_many(template, cands, seeds)
+        added.append(misses() - before)
+    distinct = len(set(sum(cpu, []))) + len(set(sum(mem, [])))
+    assert added == [distinct, 0]
 
 
 def _ranks(template):
