@@ -1014,9 +1014,11 @@ def _fold_repeats(values: np.ndarray, sizes: Sequence[int],
 
 #: lazily-built jitted sweep — see _jax_sweep_fn
 _JAX_SWEEP = None
-#: (C', N, ((width, in-degree) per rank)) signatures the jitted sweep
-#: has been given: a new one is a trace plus a compile or a
-#: persistent-cache load
+#: the most predecessor slots one gather block of the jitted sweep
+#: reads: a block's temporaries are (C', N, at most this many) floats
+_SWEEP_BLOCK = 1024
+#: (C', N, table shapes, columns) signatures the jitted sweep has been
+#: given: a new one is a trace plus a compile or a persistent-cache load
 _SWEEP_SHAPES: set = set()
 #: sequence numbers of ``run_many`` calls, the ``call`` stat of their spans
 _CALLS = itertools.count(1)
@@ -1025,54 +1027,86 @@ _CALLS = itertools.count(1)
 def _jax_sweep_fn():
     """Build (once) the jitted fleet step behind
     ``FleetEngine(plane_backend="jax")``: one step per topological rank
-    sets that rank's columns of the (candidates, instances, nodes)
-    finish-time tensor, all in a single device program. Columns are in
-    rank order; ``preds[r]`` holds the columns of rank r's predecessors,
-    padded to the rank's largest in-degree (``masks[r]`` marks the live
-    slots). Rank 0 is the sources, which gather nothing. Import of jax
-    is deferred to first use so numpy-only deployments never pay for
-    it."""
+    sets that rank's columns of the (candidates, instances, columns)
+    finish-time tensor, all in a single device program.
+
+    ``rt`` holds the runtimes in the column layout of
+    :func:`_rank_tables`: the sources, then each later rank padded to a
+    whole number of row blocks; one more column, past them, always
+    holds -inf. ``tables[r]`` is a (row blocks, slot blocks, slots,
+    rows) array of predecessor columns; a padding slot names the -inf
+    column. A rank is swept block by block in two bounded loops (one of
+    a single step is traced as its body): each block gathers ``slots x
+    rows`` finishes (at most ``_SWEEP_BLOCK``) and folds their max into
+    the rows' start, and each row block is written once all its slot
+    blocks are in. So the traced program grows with the number of ranks
+    only, never with an in-degree, and its temporaries beyond the
+    finish tensor are one block. Import of jax is deferred to first use
+    so numpy-only deployments never pay for it."""
     global _JAX_SWEEP
     if _JAX_SWEEP is None:
         import jax
         import jax.numpy as jnp
+        from jax import lax
 
         @jax.jit
-        def sweep(t_all, rt, preds, masks):
+        def sweep(t_all, rt, tables):
             c, n = rt.shape[0], t_all.shape[0]
-            fin = jnp.zeros((c, n, rt.shape[1]), dtype=rt.dtype)
-            lo = 0
-            # the ranks are few and differ in shape: unrolled, each
-            # gathers only its own predecessors
-            for pidx, pmask in zip(preds, masks):
-                w = pidx.shape[0]
-                if pidx.shape[1] == 0:
-                    # a source starts at the arrival instant
-                    start = jnp.broadcast_to(t_all[None, :, None], (c, n, w))
-                else:
-                    # everything else starts at the max of its
-                    # predecessors' finishes, slot by slot — the same
-                    # recurrence the numpy sweep runs per node (slot 0
-                    # always holds an edge; padding reads -inf)
-                    start = fin[:, :, pidx[:, 0]]
-                    for j in range(1, pidx.shape[1]):
-                        start = jnp.maximum(start, jnp.where(
-                            pmask[None, None, :, j], fin[:, :, pidx[:, j]],
-                            -jnp.inf))
-                fin = fin.at[:, :, lo:lo + w].set(
-                    start + rt[:, None, lo:lo + w])
-                lo += w
+            lo = rt.shape[1] - sum(t.shape[0] * t.shape[3] for t in tables)
+            # sources start at the arrival instant; every other column,
+            # the -inf one included, holds -inf until its rank is swept
+            fin = jnp.full((c, n, rt.shape[1] + 1), -jnp.inf, rt.dtype)
+            fin = fin.at[:, :, :lo].set(t_all[None, :, None]
+                                        + rt[:, None, :lo])
+
+            def loop(steps, body, carry):
+                # a loop of one step is its body: XLA keeps a one-trip
+                # while loop, and copies its carry in and out of it
+                return body(0, carry) if steps == 1 \
+                    else lax.fori_loop(0, steps, body, carry)
+
+            for table in tables:
+                _, nk, bk, bw = table.shape
+
+                # each body is traced at once, inside this pass of the
+                # loop over tables
+                def rows(wb, fin):
+                    def slots(kb, start):
+                        idx = table[wb, kb].reshape(-1)
+                        got = fin[:, :, idx].reshape(c, n, bk, bw)
+                        return jnp.maximum(start, got.max(axis=2))
+
+                    # the max of the predecessors' finishes: the same
+                    # recurrence the numpy sweep runs per node (a max is
+                    # exact, so the order of the slots leaves every bit)
+                    start = loop(nk, slots, jnp.full((c, n, bw), -jnp.inf,
+                                                     rt.dtype))
+                    at = lo + wb * bw
+                    return lax.dynamic_update_slice_in_dim(
+                        fin, start + lax.dynamic_slice_in_dim(
+                            rt, at, bw, axis=1)[:, None, :], at, axis=2)
+
+                fin = loop(table.shape[0], rows, fin)
+                lo += table.shape[0] * bw
             return fin.max(axis=2)
 
         _JAX_SWEEP = sweep
     return _JAX_SWEEP
 
 
-def _rank_tables(template, order):
-    """The jitted sweep's topology: the functions in rank order (rank =
-    longest hop count from a source; topological order within a rank)
-    and, per rank, the (width, in-degree) tables of their predecessors'
-    positions in that order and of the live slots."""
+def _block_shape(width: int, indegree: int) -> Tuple[int, int, int, int]:
+    """A rank's (row blocks, slot blocks, slots, rows) in the jitted
+    sweep: blocks of at most ``_SWEEP_BLOCK`` slots, as even as the
+    in-degree and the width allow."""
+    nk = -(-indegree // _SWEEP_BLOCK)
+    bk = -(-indegree // nk)
+    nw = -(-width // max(_SWEEP_BLOCK // bk, 1))
+    return nw, nk, bk, -(-width // nw)
+
+
+def _ranks(template, order) -> List[List[str]]:
+    """The functions grouped by rank (longest hop count from a source),
+    in topological order within a rank."""
     rank: Dict[str, int] = {}
     for name in order:
         rank[name] = 1 + max((rank[p] for p in template.predecessors(name)),
@@ -1080,20 +1114,35 @@ def _rank_tables(template, order):
     by_rank: List[List[str]] = [[] for _ in range(max(rank.values()) + 1)]
     for name in order:
         by_rank[rank[name]].append(name)
-    ranked = [name for names in by_rank for name in names]
-    pos = {name: i for i, name in enumerate(ranked)}
-    preds, masks = [], []
-    for names in by_rank:
-        width = max(len(template.predecessors(n)) for n in names)
-        idx = np.zeros((len(names), width), dtype=np.int32)
-        live = np.zeros((len(names), width), dtype=bool)
+    return by_rank
+
+
+def _rank_tables(template, by_rank):
+    """The jitted sweep's topology (see :func:`_jax_sweep_fn`): the
+    column of each function, the number of columns, and per later rank
+    the (row blocks, slot blocks, slots, rows) table of its
+    predecessors' columns. The sources take the first columns; each
+    later rank takes its width padded to whole row blocks. Padding
+    slots and padding rows read the -inf column, the one past the
+    last."""
+    preds = [[template.predecessors(n) for n in names]
+             for names in by_rank[1:]]
+    shapes = [_block_shape(len(p), max(map(len, p))) for p in preds]
+    pos: Dict[str, int] = {}
+    width = 0
+    for names, shape in zip(by_rank, [None, *shapes]):
         for k, name in enumerate(names):
-            for j, p in enumerate(template.predecessors(name)):
-                idx[k, j] = pos[p]
-                live[k, j] = True
-        preds.append(idx)
-        masks.append(live)
-    return ranked, tuple(preds), tuple(masks)
+            pos[name] = width + k
+        width += len(names) if shape is None else shape[0] * shape[3]
+    tables = []
+    for rows, (nw, nk, bk, bw) in zip(preds, shapes):
+        # (rows, slots) padded with the -inf column, then cut in blocks
+        flat = np.full((nw * bw, nk * bk), width, dtype=np.int32)
+        for k, row in enumerate(rows):
+            flat[k, :len(row)] = [pos[p] for p in row]
+        tables.append(np.ascontiguousarray(
+            flat.reshape(nw, bw, nk, bk).transpose(0, 2, 3, 1)))
+    return pos, width, tuple(tables)
 
 
 def _cell_span(call: int, plane: str):
@@ -2156,14 +2205,16 @@ class FleetEngine:
         """The fast plane's longest-path sweep as one jitted device
         program (x64), one step per topological rank: all C×N×V finish
         times advance in as many steps as the workflow is deep, and each
-        rank gathers only its own predecessors. Counts the steps, the
-        gathered slots (padding included) and the live edges of each
-        sweep (``fleet.sweep.steps``, ``.slots``, ``.edges``). Same
-        recurrence as the numpy sweep, bit-identical where float64 is
-        native (the CPU; validated by tests). A TPU emulates float64: on
-        a TPU v5e the results differ from numpy by up to 6.4e-14
-        relative (``chip_smoke.py`` holds them to 1e-12). Requires
-        jax."""
+        rank gathers its predecessors in blocks of at most
+        ``_SWEEP_BLOCK`` slots. Builds the rank tables once per template
+        structure (span ``fleet.sweep.tables``) and counts, per sweep,
+        the steps, the gather blocks, the gathered slots (padding
+        included) and the live edges (``fleet.sweep.steps``,
+        ``.blocks``, ``.slots``, ``.edges``). Same recurrence as the
+        numpy sweep, bit-identical where float64 is native (the CPU;
+        validated by tests). A TPU emulates float64: on a TPU v5e the
+        results differ from numpy by up to 6.4e-14 relative
+        (``chip_smoke.py`` holds them to 1e-12). Requires jax."""
         import jax
         sweep = _jax_sweep_fn()
         # the tables hold while the template's structure does: every
@@ -2171,26 +2222,34 @@ class FleetEngine:
         kept = self._sweep_tables
         if kept is None or kept["template"] is not template \
                 or kept["topo"] is not template._topo:
-            ranked, preds, masks = _rank_tables(template, order)
-            kept = {"template": template, "topo": template._topo,
-                    "cols": np.array([col[name] for name in ranked]),
-                    "slots": sum(p.size for p in preds),
-                    "edges": sum(int(m.sum()) for m in masks)}
-            with jax.enable_x64(True):
-                kept["preds"], kept["masks"] = jax.device_put((preds,
-                                                               masks))
+            by_rank = _ranks(template, order)
+            indegree = [len(template.predecessors(n)) for n in order]
+            with telemetry.span("fleet.sweep.tables", call=call,
+                                ranks=len(by_rank), edges=sum(indegree),
+                                fanin=max(indegree)):
+                pos, width, tables = _rank_tables(template, by_rank)
+                # padding columns read any real runtime: -inf stays -inf
+                cols = np.zeros(width, dtype=np.intp)
+                cols[list(pos.values())] = [col[n] for n in pos]
+                kept = {"template": template, "topo": template._topo,
+                        "cols": cols, "edges": sum(indegree),
+                        "blocks": sum(t.shape[0] * t.shape[1]
+                                      for t in tables),
+                        "slots": sum(t.size for t in tables)}
+                with jax.enable_x64(True):
+                    kept["tables"] = jax.device_put(tables)
             self._sweep_tables = kept
-        telemetry.count("fleet.sweep.steps", len(kept["preds"]))
+        telemetry.count("fleet.sweep.steps", 1 + len(kept["tables"]))
+        telemetry.count("fleet.sweep.blocks", kept["blocks"])
         telemetry.count("fleet.sweep.slots", kept["slots"])
         telemetry.count("fleet.sweep.edges", kept["edges"])
-        shape = (rt.shape[0], t_all.shape[0],
-                 tuple(p.shape for p in kept["preds"]))
+        shape = (rt.shape[0], t_all.shape[0], kept["cols"].size,
+                 tuple(t.shape for t in kept["tables"]))
         if shape not in _SWEEP_SHAPES:
             _SWEEP_SHAPES.add(shape)
             telemetry.count("fleet.sweep.shapes")
         with jax.enable_x64(True):
-            fin = sweep(t_all, rt[:, kept["cols"]], kept["preds"],
-                        kept["masks"])
+            fin = sweep(t_all, rt[:, kept["cols"]], kept["tables"])
             self.sweep_device = fin.device
             with telemetry.span("fleet.fetch", call=call):
                 return np.asarray(fin)

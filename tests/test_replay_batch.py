@@ -759,6 +759,72 @@ def test_jax_sweep_follows_a_structural_edit_of_the_template():
         assert_reports_identical(got, want)
 
 
+def mixed_fan_in(k: int, heavy: str):
+    """Sources, then one rank of four functions with in-degrees 1, 2,
+    10 and ``k``, then a join of the four and a chain after it. Each of
+    the four reads the sources just before the last; the ``k``-wide one
+    reads the last too, as its last slot. One source does a hundred
+    times the others' work: with ``heavy="last"`` the last, so the
+    ``k``-wide function's last slot (in its last slot block) decides
+    every finish; with ``heavy="first"`` the first, which nothing reads,
+    so a padding slot that read its column and not the -inf one would
+    move a narrow function past it."""
+    from repro.core.dag import Workflow
+    from repro.serverless.function import FunctionSpec
+
+    template = Workflow(f"fan_in_{k}_{heavy}")
+
+    def add(name, i, work=5.0):
+        template.add_function(name, payload=FunctionSpec(
+            name=name, cpu_work=work + (i * 37) % 23,
+            parallel_frac=0.5 + (i % 7) * 0.05, mem_floor=256.0,
+            mem_knee=1024.0, mem_penalty=1.5, io_time=0.5 + (i % 5) * 0.25))
+
+    sources = [f"s{i:04d}" for i in range(max(k, 12) + 1)]
+    big = sources[-1] if heavy == "last" else sources[0]
+    for i, name in enumerate(sources):
+        add(name, i, 500.0 if name == big else 5.0)
+    mids = []
+    for j, degree in enumerate((1, 2, 10, k)):
+        add(f"m{j}", j)
+        mids.append(f"m{j}")
+        reads = sources[-k:] if j == 3 else sources[-degree - 1:-1]
+        for name in reads:
+            template.add_edge(name, f"m{j}")
+    add("join", 1)
+    for name in mids:
+        template.add_edge(name, "join")
+    add("tail", 2)
+    template.add_edge("join", "tail")
+    return template
+
+
+@pytest.mark.parametrize("heavy", ["last", "first"])
+@pytest.mark.parametrize("k", [1, 2, 10, 300, 1100])
+def test_jax_sweep_matches_numpy_and_scalar_at_any_fan_in(k, heavy):
+    """Ranks of mixed in-degree (1, 2, 10 and ``k`` side by side, so
+    the narrow rows are padded to ``k`` slots, and at 1,100 cut into two
+    slot blocks): the jitted sweep's reports are the numpy plane's and
+    the scalar loop's, bit for bit."""
+    pytest.importorskip("jax")
+    template = mixed_fan_in(k, heavy)
+    assert max(len(template.predecessors(n)) for n in template.nodes) \
+        == max(k, 10)
+    cands = candidate_sets(template, 2, seed=23)
+    seeds = arrival_sets(2, n=4)
+    jax_reports = make_engine(plane_backend="jax").run_many(template, cands,
+                                                            seeds)
+    numpy_reports = make_engine().run_many(template, cands, seeds)
+    engine = make_engine()
+    k_cell = 0
+    for configs in cands:
+        for times in seeds:
+            want = scalar_cell(engine, template, configs, times)
+            assert_reports_identical(jax_reports[k_cell], want)
+            assert_reports_identical(numpy_reports[k_cell], want)
+            k_cell += 1
+
+
 #: values whose running sums round half to even, reach the subnormals,
 #: stay huge, or start from -0.0 (the scalar loop starts from +0.0)
 FOLD_VALUES = [0.1, 3.0000000000000004, 2.0 ** -1074, 1e300, -0.0]

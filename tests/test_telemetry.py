@@ -211,10 +211,12 @@ def test_counters_name_the_plane_batch_eligibility_reports(plane,
                 _distinct_runtimes(plane, template, cands)
         if plane == "fast":
             # the jitted sweep (the stochastic plane sweeps in numpy):
-            # one step per rank, each gathering its own predecessors
+            # one step per rank, each padded to its largest in-degree
             ranks = _ranks(template)
             preds = [len(template.predecessors(n)) for n in template.nodes]
             assert delta.pop("fleet.sweep.steps") == len(ranks)
+            # each rank after the sources fits one gather block
+            assert delta.pop("fleet.sweep.blocks") == len(ranks) - 1
             assert delta.pop("fleet.sweep.slots") == sum(
                 len(r) * max(len(template.predecessors(n)) for n in r)
                 for r in ranks)
@@ -288,22 +290,106 @@ def _ranks(template):
             for r in range(max(rank.values()) + 1)]
 
 
+SWEEP_COUNTERS = ("fleet.sweep.steps", "fleet.sweep.blocks",
+                  "fleet.sweep.slots", "fleet.sweep.edges")
+
+
+def _sweep_counts(engine, template, seeds):
+    before = telemetry.counters()
+    engine.run_many(template, [{}, {}], seeds)
+    after = telemetry.counters()
+    return {k: after[k] - before.get(k, 0) for k in SWEEP_COUNTERS}
+
+
 def test_sweep_counters_count_steps_padded_slots_and_edges():
     """A fan-in, three ranks deep: the source gathers nothing, each of
     the 5 branches gathers its one predecessor, and the join gathers
-    all 5 — 10 slots, every one an edge."""
+    all 5 — 10 slots in two blocks, every slot an edge."""
     template = fan_workflow(5, seed=2)
     _, _, seeds = _inputs()
     engine = _engine("fast")
     for _ in range(2):
-        before = telemetry.counters()
-        engine.run_many(template, [{}, {}], seeds)
-        after = telemetry.counters()
-        assert {k: after[k] - before.get(k, 0) for k in (
-            "fleet.sweep.steps", "fleet.sweep.slots",
-            "fleet.sweep.edges")} == {"fleet.sweep.steps": 3,
-                                      "fleet.sweep.slots": 5 + 5,
-                                      "fleet.sweep.edges": 10}
+        assert _sweep_counts(engine, template, seeds) == {
+            "fleet.sweep.steps": 3, "fleet.sweep.blocks": 2,
+            "fleet.sweep.slots": 5 + 5, "fleet.sweep.edges": 10}
+
+
+def test_sweep_blocks_follow_slots_not_an_in_degree():
+    """A fan of 1,100 branches: the branches' rank is wider than a
+    block of 1,024 slots, and the join's in-degree is larger than one;
+    each is cut into two even blocks of 550, with no padding."""
+    from repro.core.engine import _SWEEP_BLOCK
+    assert _SWEEP_BLOCK < 1100 <= 2 * _SWEEP_BLOCK
+    template = fan_workflow(1100, seed=2)
+    _, _, seeds = _inputs(n=3)
+    assert _sweep_counts(_engine("fast"), template, seeds) == {
+        "fleet.sweep.steps": 3, "fleet.sweep.blocks": 2 + 2,
+        "fleet.sweep.slots": 1100 + 1100, "fleet.sweep.edges": 2200}
+
+
+def test_rank_tables_are_built_once_per_structure_in_a_span():
+    """``fleet.sweep.tables`` wraps the first sweep of a template's
+    structure, inside the call's ``fleet.sweep``, with the ranks, the
+    edges and the largest in-degree; a second call keeps the tables."""
+    template, cands, seeds = _inputs()
+    engine = _engine("fast")
+
+    def two_calls():
+        engine.run_many(template, cands, seeds)
+        engine.run_many(template, cands, seeds)
+
+    _, spans = _traced(two_calls)
+    tables, = [s for s in spans if s[2] == "fleet.sweep.tables"]
+    sweep = next(s for s in spans if s[2] == "fleet.sweep"
+                 and s[3]["call"] == tables[3]["call"])
+    assert _inside(tables, sweep)
+    preds = [len(template.predecessors(n)) for n in template.nodes]
+    assert {k: tables[3][k] for k in ("ranks", "edges", "fanin")} == {
+        "ranks": len(_ranks(template)), "edges": sum(preds),
+        "fanin": max(preds)}
+
+
+def _equations(jaxpr) -> int:
+    """Equations of a jaxpr, those of the jaxprs inside it included."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                if hasattr(sub, "eqns"):
+                    count += _equations(sub)
+    return count
+
+
+def _sweep_equations(ranks, c=8, n=64) -> int:
+    """Equations of the jitted sweep traced for ``ranks``, a (width,
+    in-degree) per rank, the sources first."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.engine import _block_shape, _jax_sweep_fn
+
+    shapes = [_block_shape(w, k) for w, k in ranks[1:]]
+    width = ranks[0][0] + sum(nw * bw for nw, _, _, bw in shapes)
+    with jax.enable_x64(True):
+        return _equations(jax.make_jaxpr(_jax_sweep_fn())(
+            jax.ShapeDtypeStruct((n,), jnp.float64),
+            jax.ShapeDtypeStruct((c, width), jnp.float64),
+            tuple(jax.ShapeDtypeStruct(s, jnp.int32) for s in shapes)))
+
+
+def test_sweep_program_does_not_grow_with_fan_in():
+    """The same three ranks with a join of in-degree 8, 930 (Montage's
+    ``mConcatFit``), 3,000 or 6,000 (three or six slot blocks): one
+    program size while each rank fits one block, and one more, by the
+    loops' own few equations, once its blocks take a loop, whatever its
+    trip count. A rank more adds the same equations each time."""
+    sizes = {k: _sweep_equations([(k, 0), (k, 1), (1, k)])
+             for k in (8, 930, 3000, 6000)}
+    assert sizes[8] == sizes[930] and sizes[3000] == sizes[6000], sizes
+    assert 0 < sizes[3000] - sizes[930] < sizes[930], sizes
+    deeper = [_sweep_equations([(4, 0)] + [(4, 2)] * r) for r in (1, 2, 3)]
+    assert deeper[2] - deeper[1] == deeper[1] - deeper[0] > 0
 
 
 @pytest.mark.parametrize("plane", ["fast", "constrained"])

@@ -71,12 +71,13 @@ def test_ssd_scan_compiles_at_zamba2_widths(one_chip):
 
 def _sweep_specs(sharding, c, i, ranks):
     """The jitted sweep's arguments for ``ranks``, a (width, in-degree)
-    per topological rank."""
-    v = sum(w for w, _ in ranks)
+    per topological rank, the sources first."""
+    from repro.core.engine import _block_shape
+    shapes = [_block_shape(w, k) for w, k in ranks[1:]]
+    width = ranks[0][0] + sum(nw * bw for nw, _, _, bw in shapes)
     return (_spec(sharding, (i,), jnp.float64),
-            _spec(sharding, (c, v), jnp.float64),
-            tuple(_spec(sharding, r, jnp.int32) for r in ranks),
-            tuple(_spec(sharding, r, jnp.bool_) for r in ranks))
+            _spec(sharding, (c, width), jnp.float64),
+            tuple(_spec(sharding, s, jnp.int32) for s in shapes))
 
 
 def test_fleet_sweep_compiles_in_float64(one_chip):
@@ -105,3 +106,25 @@ def test_fleet_sweep_compiles_at_1000genome_width(one_chip):
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes == c * i * 8
     assert c * i * v * 8 <= mem.temp_size_in_bytes < 16e9
+
+
+def test_fleet_sweep_compiles_at_montage_width(one_chip):
+    """The rank sweep of the Montage mosaic (3 bands of 16x16 images: 768
+    projections, 2,790 overlap fits, 3 fits of in-degree 930, 768
+    background corrections, the per-band tails and one join) at C=8,
+    I=4096. Beyond the (C, I, V) finish tensor the program holds one
+    gather block: the gathered slots, their max and the rows' start, at
+    most ``_SWEEP_BLOCK`` columns each."""
+    from repro.core.engine import _SWEEP_BLOCK, _jax_sweep_fn
+    c, i, v = 8, 4096, 4342
+    ranks = [(768, 0), (2790, 2), (3, 930), (3, 1), (768, 2), (3, 256),
+             (3, 1), (3, 1), (1, 3)]
+    assert sum(w for w, _ in ranks) == v
+    with jax.enable_x64(True):
+        compiled = _jax_sweep_fn().lower(*_sweep_specs(
+            one_chip, c, i, ranks)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == c * i * 8
+    finish = c * i * (v + 1) * 8
+    block = c * i * _SWEEP_BLOCK * 8
+    assert finish <= mem.temp_size_in_bytes <= finish + 3 * block < 16e9
